@@ -30,6 +30,9 @@ import sys
 import time
 
 from repro.core import telemetry
+from repro.launch.compile_cache import use_compile_cache
+
+use_compile_cache()
 
 _ROWS: list = []
 _FLUSHED: set = set()   # stems written this process (double-flush guard)

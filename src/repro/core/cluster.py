@@ -9,7 +9,7 @@ actual tokens while benchmarks read the modeled transfer timelines.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,7 +64,8 @@ class DejaVuCluster:
                  host_cache_blocks: Optional[int] = None,
                  ssd_cache_blocks: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
-                 fused_rounds: Optional[bool] = None):
+                 fused_rounds: Optional[bool] = None,
+                 devices: Optional[Sequence[jax.Device]] = None):
         assert mode in ("colocated", "disaggregated")
         if mode == "disaggregated":
             assert dp_split is not None and sum(dp_split) == n_workers
@@ -72,7 +73,12 @@ class DejaVuCluster:
             assert paged, "tiered=True requires paged=True"
         self.cfg = cfg
         self.model = model
-        self.params = params             # full weights (the checkpoint store)
+        # full weights: the checkpoint store, kept in host memory; each
+        # stage holds only its own slice on its device
+        self.params = jax.device_get(params)
+        # worker `wid` runs on devices[wid % len(devices)]: one stage per
+        # chip where there are enough, all on one chip when given one
+        self.devices = list(devices) if devices else jax.local_devices()
         self.mode = mode
         self.swapping = swapping
         self.replication = replication
@@ -153,8 +159,12 @@ class DejaVuCluster:
             ws.append(StageWorker(wid0 + i, self.model, self.params, lo, hi,
                                   first=(i == 0), last=(i == len(ranges) - 1),
                                   role=role, hw=self.hw, streamer=self.streamer,
-                                  compress_replicas=self.compress_replicas))
+                                  compress_replicas=self.compress_replicas,
+                                  device=self._device_for(wid0 + i)))
         return ws
+
+    def _device_for(self, wid: int) -> jax.Device:
+        return self.devices[wid % len(self.devices)]
 
     def _topo(self, group: List[StageWorker]) -> PipelineTopo:
         return PipelineTopo(depth=len(group), num_layers=self.cfg.num_layers,
@@ -177,7 +187,7 @@ class DejaVuCluster:
                 tracing.span("pass", kind="mb_prefill", mb=mb, batch=b):
             x = tokens
             for w in self.prompt_group:
-                x = w.prefill(mb, x, max_len)
+                x = w.prefill(mb, w.put(x), max_len)
             telemetry.advance(cm.stage_prompt_time(
                 self.cfg, cm.WorkloadSpec(prompt_len=plen, new_tokens=1,
                                           microbatch=b),
@@ -232,7 +242,7 @@ class DejaVuCluster:
                     w.restore(mb)
             x = token
             for w in self.token_group:
-                x = w.decode(mb, x, pos)
+                x = w.decode(mb, w.put(x), pos)
             telemetry.advance(cm.stage_token_time(
                 self.cfg, cm.WorkloadSpec(prompt_len=max(pos, 1), new_tokens=1,
                                           microbatch=self.mb_batch.get(mb, 1)),
@@ -382,21 +392,21 @@ class DejaVuCluster:
                 tracing.span("pass", rid=rid, kind=f"prefill_{st['mode']}",
                              pos=pos, plen=plen):
             if st["mode"] == "batch":
-                x = jnp.asarray(st["prompt"])[None]
+                x = st["prompt"][None]
                 for w in self.prompt_group:
                     x, _ = w.prefill_paged(
-                        rid, x, token_ids=[int(t) for t in st["prompt"]])
+                        rid, w.put(x), token_ids=[int(t) for t in st["prompt"]])
                 n_q = plen
             elif st["mode"] == "chunk":
                 c = min(self.prefill_chunk_tokens, plen - pos)
-                x = jnp.asarray(st["prompt"][pos:pos + c])[None]
+                x = st["prompt"][None, pos:pos + c]
                 for w in self.prompt_group:
-                    x = w.prefill_chunk_paged(rid, x, pos)
+                    x = w.prefill_chunk_paged(rid, w.put(x), pos)
                 n_q = c
             else:                        # token-at-a-time oracle path
-                x = jnp.asarray(st["prompt"][pos:pos + 1])
+                x = st["prompt"][pos:pos + 1]
                 for w in self.prompt_group:
-                    x = w.decode_paged(rid, x, pos)
+                    x = w.decode_paged(rid, w.put(x), pos)
                 n_q = 1
             st["x"] = x
             self._after_prefill_pass(rid, st, n_q)
@@ -512,7 +522,7 @@ class DejaVuCluster:
                         f"worker {w.wid} pool full (seq {rid})")
             x = token
             for w in self.token_group:
-                x = w.decode_paged(rid, x, pos)
+                x = w.decode_paged(rid, w.put(x), pos)
             self.seq_len[rid] = pos + 1
             self._register_compute(1, pos + 1)
             telemetry.advance(cm.stage_token_time(
@@ -559,9 +569,9 @@ class DejaVuCluster:
                         f"worker {w.wid} pool cannot absorb a fused round of "
                         f"{len(rids)} appends ({need} needed, "
                         f"{w.pool.num_free()} free)")
-            x = jnp.asarray(np.asarray(tokens, np.int32))
+            x = np.asarray(tokens, np.int32)
             for w in self.token_group:
-                x = w.decode_paged_batch(rids, x, poses)
+                x = w.decode_paged_batch(rids, w.put(x), poses)
             for rid, pos in zip(rids, poses):
                 self.seq_len[rid] = pos + 1
                 self._register_compute(1, pos + 1)
@@ -606,9 +616,9 @@ class DejaVuCluster:
         for i, st in enumerate(sts):
             toks[i, :cs[i]] = st["prompt"][st["pos"]:st["pos"] + cs[i]]
         pos0s = [st["pos"] for st in sts]
-        x = jnp.asarray(toks)
+        x = toks
         for w in self.prompt_group:
-            x = w.prefill_chunk_paged_batch(rids, x, pos0s, cs)
+            x = w.prefill_chunk_paged_batch(rids, w.put(x), pos0s, cs)
         out: Dict[int, Optional[jnp.ndarray]] = {}
         for i, (rid, st) in enumerate(zip(rids, sts)):
             self._after_prefill_pass(rid, st, cs[i])
@@ -700,8 +710,8 @@ class DejaVuCluster:
                 continue
             kv = w.kv.get(mb)
             if kv is None:      # swapped out: replicate from host copy
-                kv = {leaf: jnp.asarray(w.cache.host.get(f"swap/mb{mb}/{leaf}"))
-                      for leaf in ("k", "v")}
+                kv = w.put({leaf: w.cache.host.get(f"swap/mb{mb}/{leaf}")
+                            for leaf in ("k", "v")})
             peer = group[(i + 1) % n]
             w.cache.replicate_to(peer.cache, mb, kv, token_range, step,
                                  self.controller.ack_replication)
@@ -753,7 +763,8 @@ class DejaVuCluster:
             old = self.prompt_group[idx]
             neww = StageWorker(wid, self.model, self.params, lo, hi,
                                first=old.first, last=old.last, role=old.role,
-                               hw=self.hw, streamer=self.streamer)
+                               hw=self.hw, streamer=self.streamer,
+                               device=old.device)
             if self.paged:
                 neww.enable_paging(self.kv_pool_blocks, self.kv_block_size)
                 if self.tiered:
@@ -773,7 +784,8 @@ class DejaVuCluster:
         neww = StageWorker(wid, self.model, self.params, lo, hi,
                            first=old.first, last=old.last, role=old.role,
                            hw=self.hw, streamer=self.streamer,
-                           compress_replicas=self.compress_replicas)
+                           compress_replicas=self.compress_replicas,
+                           device=old.device)
         group[idx] = neww
         self.controller.workers = [neww if w.wid == wid else w
                                    for w in self.controller.workers]
@@ -797,8 +809,8 @@ class DejaVuCluster:
         for mb in active_mbs:
             kv = pred.kv.get(mb)
             if kv is None and pred.cache.host_has(mb):
-                kv = {leaf: jnp.asarray(pred.cache.host.get(f"swap/mb{mb}/{leaf}"))
-                      for leaf in ("k", "v")}
+                kv = pred.put({leaf: pred.cache.host.get(f"swap/mb{mb}/{leaf}")
+                               for leaf in ("k", "v")})
             if kv is not None:
                 pred.cache.replicate_to(neww.cache, mb, kv,
                                         (0, self.mb_pos[mb]),
@@ -917,7 +929,8 @@ class DejaVuCluster:
             new_group.append(StageWorker(
                 wid0 + i, self.model, self.params, lo, hi, first=(i == 0),
                 last=(i == len(ranges) - 1),
-                role=old_group[0].role, hw=self.hw, streamer=self.streamer))
+                role=old_group[0].role, hw=self.hw, streamer=self.streamer,
+                device=self._device_for(wid0 + i)))
         if self.paged:
             for w in new_group:
                 w.enable_paging(self.kv_pool_blocks, self.kv_block_size)

@@ -76,13 +76,14 @@ class CacheManager:
             dense[:, :, t0a:t0a + w] = packed
             self.host.put(key, dense)
 
-    def swap_in(self, mb: int, shape, dtype) -> Dict[str, jax.Array]:
+    def swap_in(self, mb: int) -> Dict[str, np.ndarray]:
+        """The microbatch's host copy, charged to the host link; the worker
+        places it on its device."""
         out = {}
         for leaf in ("k", "v"):
             key = f"swap/mb{mb}/{leaf}"
-            arr = self.host.get(key)
-            self.hostlink.transfer(arr, tag=key)
-            out[leaf] = jnp.asarray(arr)
+            out[leaf] = self.host.get(key)
+            self.hostlink.transfer(out[leaf], tag=key)
         return out
 
     def host_has(self, mb: int) -> bool:
@@ -202,13 +203,18 @@ class CacheManager:
 
 
 class StageWorker:
-    """One pipeline stage (a machine with `chips` accelerators running TP)."""
+    """One pipeline stage (a machine with `chips` accelerators running TP).
+
+    The stage runs on `device` (default: the first local device): its slice
+    of the parameters and every KV array it computes on are placed there,
+    so its jitted stage functions execute there."""
 
     def __init__(self, wid: int, model, full_params, lo: int, hi: int, *,
                  first: bool, last: bool, role: str = "both",
                  hw: HardwareModel = DEFAULT_HW,
                  streamer: Optional[StreamEngine] = None,
-                 compress_replicas: bool = False):
+                 compress_replicas: bool = False,
+                 device: Optional[jax.Device] = None):
         self.wid = wid
         self.model = model
         self.lo, self.hi = lo, hi
@@ -216,8 +222,10 @@ class StageWorker:
         self.role = role                      # "prompt" | "token" | "both"
         self.alive = True
         self.hw = hw
+        self.device = device if device is not None else jax.local_devices()[0]
         self.last_heartbeat = time.monotonic()
-        self.sp = model.slice_params(full_params, lo, hi, first=first, last=last)
+        self.sp = self.put(model.slice_params(full_params, lo, hi,
+                                              first=first, last=last))
         self.kv: Dict[int, Dict[str, jax.Array]] = {}   # device-resident slots
         self.cache = CacheManager(wid, hw, streamer or StreamEngine(f"w{wid}"),
                                   compress_replicas=compress_replicas)
@@ -229,36 +237,16 @@ class StageWorker:
         self.paged_dirty: Dict[int, set] = {}       # seq -> dirty logical blocks
         self.paged_swapped: Dict[int, int] = {}     # seq -> offloaded length
 
-        mf = model
-        if first:
-            self._prefill = jax.jit(lambda sp, tokens: mf.stage_prefill(
-                sp, None, first=True, last=last, tokens=tokens))
-            self._decode = jax.jit(lambda sp, token, kc, vc, pos: mf.stage_decode(
-                sp, None, kc, vc, pos, first=True, last=last, token=token))
-            self._prefill_chunk = jax.jit(
-                lambda sp, tokens, kc, vc, pos: mf.stage_prefill_chunk(
-                    sp, None, kc, vc, pos, first=True, last=last, tokens=tokens))
-            self._decode_batch = jax.jit(
-                lambda sp, token, kc, vc, pos: mf.stage_decode_batch(
-                    sp, None, kc, vc, pos, first=True, last=last, token=token))
-            self._prefill_chunk_batch = jax.jit(
-                lambda sp, tokens, kc, vc, pos, ql: mf.stage_prefill_chunk_batch(
-                    sp, None, kc, vc, pos, ql, first=True, last=last,
-                    tokens=tokens))
-        else:
-            self._prefill = jax.jit(lambda sp, x: mf.stage_prefill(
-                sp, x, first=False, last=last))
-            self._decode = jax.jit(lambda sp, x, kc, vc, pos: mf.stage_decode(
-                sp, x, kc, vc, pos, first=False, last=last))
-            self._prefill_chunk = jax.jit(
-                lambda sp, x, kc, vc, pos: mf.stage_prefill_chunk(
-                    sp, x, kc, vc, pos, first=False, last=last))
-            self._decode_batch = jax.jit(
-                lambda sp, x, kc, vc, pos: mf.stage_decode_batch(
-                    sp, x, kc, vc, pos, first=False, last=last))
-            self._prefill_chunk_batch = jax.jit(
-                lambda sp, x, kc, vc, pos, ql: mf.stage_prefill_chunk_batch(
-                    sp, x, kc, vc, pos, ql, first=False, last=last))
+        fns = model.stage_jits(first=first, last=last)
+        self._prefill = fns["prefill"]
+        self._decode = fns["decode"]
+        self._prefill_chunk = fns["prefill_chunk"]
+        self._decode_batch = fns["decode_batch"]
+        self._prefill_chunk_batch = fns["prefill_chunk_batch"]
+
+    def put(self, tree):
+        """Place host or device arrays on this stage's device."""
+        return jax.device_put(tree, self.device)
 
     # ------------------------------------------------------------------
     def heartbeat(self) -> bool:
@@ -307,12 +295,9 @@ class StageWorker:
     # ------------------------------------------------------------------
     def prefill(self, mb: int, x_or_tokens, max_len: int):
         self._check("prefill", mb=mb)
-        if self.first:
-            x, ks, vs = self._prefill(self.sp, x_or_tokens)
-        else:
-            x, ks, vs = self._prefill(self.sp, x_or_tokens)
-        s = ks.shape[2]
-        kc = jnp.zeros(ks.shape[:2] + (max_len,) + ks.shape[3:], ks.dtype)
+        x, ks, vs = self._prefill(self.sp, x_or_tokens)
+        kc = jnp.zeros(ks.shape[:2] + (max_len,) + ks.shape[3:], ks.dtype,
+                       device=self.device)
         vc = jnp.zeros_like(kc)
         kc = jax.lax.dynamic_update_slice_in_dim(kc, ks, 0, axis=2)
         vc = jax.lax.dynamic_update_slice_in_dim(vc, vs, 0, axis=2)
@@ -335,13 +320,13 @@ class StageWorker:
 
     def restore(self, mb: int) -> None:
         if mb not in self.kv and self.cache.host_has(mb):
-            self.kv[mb] = self.cache.swap_in(mb, None, None)
+            self.kv[mb] = self.put(self.cache.swap_in(mb))
 
     def resident(self) -> int:
         return len(self.kv)
 
     def install_kv(self, mb: int, arrays: Dict[str, np.ndarray]) -> None:
-        self.kv[mb] = {k: jnp.asarray(v) for k, v in arrays.items()}
+        self.kv[mb] = self.put(arrays)
 
     # ------------------------------------------------------------------
     # paged mode: per-sequence KV in ref-counted blocks (see kvcache.paged)
@@ -423,8 +408,8 @@ class StageWorker:
         pad_to = len(self.pool.tables[seq]) * self.pool.block_size
         dense = self.pages.gather_dense(seq, pad_to)
         x, kc, vc = self._prefill_chunk(self.sp, x_or_tokens,
-                                        jnp.asarray(dense["k"]),
-                                        jnp.asarray(dense["v"]),
+                                        self.put(dense["k"]),
+                                        self.put(dense["v"]),
                                         jnp.int32(pos0))
         self._write_chunk_window(seq, kc, vc, pos0, c, pad_to)
         return x
@@ -446,6 +431,7 @@ class StageWorker:
         # pack still covers it exactly (t0a stays tb-aligned, so any divisor
         # of tb is a valid granularity)
         tbw = tb if w % tb == 0 else math.gcd(w, tb)
+        kc, vc = self.put((kc, vc))          # the pack runs on this stage
         win = {"k": np.asarray(kops.kv_pack_auto(kc, t0a, w, token_block=tbw))[:, 0],
                "v": np.asarray(kops.kv_pack_auto(vc, t0a, w, token_block=tbw))[:, 0]}
         self.pages.write_window(seq, win, t0a)
@@ -461,8 +447,8 @@ class StageWorker:
         self.pages.apply_cow(cow)
         pad_to = len(self.pool.tables[seq]) * self.pool.block_size
         dense = self.pages.gather_dense(seq, pad_to)
-        x, kc, vc = self._decode(self.sp, x_or_token, jnp.asarray(dense["k"]),
-                                 jnp.asarray(dense["v"]), jnp.int32(pos))
+        x, kc, vc = self._decode(self.sp, x_or_token, self.put(dense["k"]),
+                                 self.put(dense["v"]), jnp.int32(pos))
         win = {"k": np.asarray(kc[:, 0, pos:pos + 1]),
                "v": np.asarray(vc[:, 0, pos:pos + 1])}
         self.pages.write_window(seq, win, pos)
@@ -475,8 +461,8 @@ class StageWorker:
         [Lstage, B, pad_to, H, D] — the fused-round stage-cache layout."""
         pad_to = max(len(self.pool.tables[s]) for s in seqs) * self.pool.block_size
         dense = [self.pages.gather_dense(s, pad_to) for s in seqs]
-        kc = jnp.asarray(np.concatenate([d["k"] for d in dense], axis=1))
-        vc = jnp.asarray(np.concatenate([d["v"] for d in dense], axis=1))
+        kc = self.put(np.concatenate([d["k"] for d in dense], axis=1))
+        vc = self.put(np.concatenate([d["v"] for d in dense], axis=1))
         return kc, vc, pad_to
 
     def decode_paged_batch(self, seqs, x_or_tokens, poses):
@@ -494,7 +480,7 @@ class StageWorker:
             cow = self.pool.append(seq)
             self.pages.apply_cow(cow)
         kc, vc, pad_to = self._gather_batch(seqs)
-        pos = jnp.asarray(np.asarray(poses, np.int32))
+        pos = self.put(np.asarray(poses, np.int32))
         x, kc, vc = self._decode_batch(self.sp, x_or_tokens, kc, vc, pos)
         tb = self.cache.token_block
         t0s = [(p // tb) * tb for p in poses]
@@ -528,8 +514,8 @@ class StageWorker:
         `ensure_prefill_table` for every sequence first."""
         self._check("chunkset", n=len(seqs))
         kc, vc, pad_to = self._gather_batch(seqs)
-        pos = jnp.asarray(np.asarray(pos0s, np.int32))
-        ql = jnp.asarray(np.asarray(q_lens, np.int32))
+        pos = self.put(np.asarray(pos0s, np.int32))
+        ql = self.put(np.asarray(q_lens, np.int32))
         x, kc, vc = self._prefill_chunk_batch(self.sp, x_or_tokens, kc, vc,
                                               pos, ql)
         kc_np, vc_np = np.asarray(kc), np.asarray(vc)

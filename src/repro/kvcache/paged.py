@@ -327,9 +327,11 @@ class BlockPool:
 class PagedKVCache:
     """Data plane for one pipeline stage: pages ``[N, Lstage, bs, Hkv, Dh]``.
 
-    Pages are host-of-truth numpy (this repro computes in interpret mode; on
-    a real TPU the same layout backs the `paged_decode_attention` kernel, and
-    these helpers become device gathers)."""
+    Pages are host-of-truth numpy on every backend, the TPU included: each
+    pass gathers a sequence's pages into a dense cache that the stage
+    uploads to its device, and writes the new window back here.  Keeping
+    pages on the device behind `paged_decode_attention` needs that kernel
+    in a head-major K/V layout first (it does not lower on TPU as is)."""
     pool: BlockPool
     layers: int
     num_kv_heads: int

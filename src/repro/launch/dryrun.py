@@ -1,7 +1,9 @@
 import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-# The two lines above MUST run before any other import (jax locks the device
-# count on first init).  Everything below may import jax.
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=512")))
+# The lines above MUST run before any other import (jax locks the device
+# count on first init); they keep whatever flags the caller set.  Everything
+# below may import jax.  Nothing on the serving path imports this module.
 
 import argparse
 import json

@@ -18,6 +18,7 @@ import numpy as np
 from repro.configs import get_arch
 from repro.core import costmodel as cm
 from repro.core.planner import plan
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import build_model
 from repro.serving import Request, ServingEngine
 
@@ -40,6 +41,7 @@ def main() -> None:
     ap.add_argument("--fail-at", default=None, help="step:worker, e.g. 12:1")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    use_compile_cache()
 
     cfg = get_arch(args.arch)
     if args.reduced:

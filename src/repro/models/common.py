@@ -29,12 +29,14 @@ def set_logical_rules(rules):
 def logical_constraint(x, *names):
     """Apply ``with_sharding_constraint`` using installed logical rules.
 
-    No-op when no rules are installed (single-device tests) or when the name
-    has no mapping.  ``names`` has one entry per axis of ``x`` (None = leave).
-    Axes whose size is not divisible by the mesh-axis extent are left
-    unconstrained (GSPMD would PAD them — e.g. batch=1 padded 16×).
+    No-op when no rules are installed (single-device tests), when no mesh
+    is active (``jax.set_mesh``), or when the name has no mapping.  Inside a
+    mesh every error of the constraint raises.  ``names`` has one entry per
+    axis of ``x`` (None = leave).  Axes whose size is not divisible by the
+    mesh-axis extent are left unconstrained (GSPMD would PAD them — e.g.
+    batch=1 padded 16×).
     """
-    if _LOGICAL_RULES is None:
+    if _LOGICAL_RULES is None or jax.sharding.get_abstract_mesh().empty:
         return x
     from jax.sharding import PartitionSpec as P
     sizes = _LOGICAL_RULES.get("__sizes__", {})
@@ -55,10 +57,7 @@ def logical_constraint(x, *names):
         spec.append(axis)
     if all(s is None for s in spec):
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, P(*spec))
-    except Exception:
-        return x  # outside a mesh context
+    return jax.lax.with_sharding_constraint(x, P(*spec))
 
 
 def activation_fn(name: str):

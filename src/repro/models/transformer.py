@@ -6,7 +6,7 @@ as per-layer xs/ys.
 """
 from __future__ import annotations
 
-from typing import Dict, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
@@ -36,6 +36,7 @@ class DecoderLM:
         self._layer_window = jnp.asarray(
             [0 if i in cfg.full_attn_layers else cfg.sliding_window
              for i in range(cfg.num_layers)], jnp.int32)
+        self._stage_jits: Dict[tuple, Dict[str, Callable]] = {}
 
     # ------------------------------------------------------------------
     def init(self, key) -> Dict:
@@ -210,6 +211,34 @@ class DecoderLM:
             elif "lm_head" in params:
                 sp["lm_head"] = params["lm_head"]
         return sp
+
+    def stage_jits(self, *, first: bool, last: bool) -> Dict[str, Callable]:
+        """The jitted stage functions of one pipeline position, built once
+        per model and shared by every worker at that position: a worker that
+        replaces a failed one, or a new cluster over the same model, reuses
+        the compiled executables instead of tracing and compiling its own
+        (a stage on another device reuses the trace and compiles only for
+        that device).  Stage 0 takes tokens where the others take
+        activations."""
+        key = (first, last)
+        if key not in self._stage_jits:
+            def stage(fn, token_kw):
+                if first:
+                    return jax.jit(lambda sp, tokens, *a: fn(
+                        sp, None, *a, first=True, last=last,
+                        **{token_kw: tokens}))
+                return jax.jit(lambda sp, x, *a: fn(
+                    sp, x, *a, first=False, last=last))
+
+            self._stage_jits[key] = {
+                "prefill": stage(self.stage_prefill, "tokens"),
+                "decode": stage(self.stage_decode, "token"),
+                "prefill_chunk": stage(self.stage_prefill_chunk, "tokens"),
+                "decode_batch": stage(self.stage_decode_batch, "token"),
+                "prefill_chunk_batch": stage(self.stage_prefill_chunk_batch,
+                                             "tokens"),
+            }
+        return self._stage_jits[key]
 
     def stage_prefill(self, sp, x, *, first: bool, last: bool,
                       tokens=None, patch_embeds=None):

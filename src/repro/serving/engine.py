@@ -28,8 +28,9 @@ bit-identically.
 from __future__ import annotations
 
 from dataclasses import asdict, dataclass, field
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -88,6 +89,9 @@ class EngineReport:
     # docs/observability.md.  Cumulative across runs when an ambient
     # registry is installed (benchmarks do this to aggregate a module).
     telemetry: Dict[str, object] = field(default_factory=dict)
+    # rid -> the logits its first token was sampled from ([V], as served):
+    # what a correctness check compares with a reference forward
+    first_token_logits: Dict[int, np.ndarray] = field(default_factory=dict)
 
 
 class ServingEngine:
@@ -104,6 +108,7 @@ class ServingEngine:
                  ssd_cache_blocks: Optional[int] = None,
                  prefill_chunk_tokens: Optional[int] = None,
                  fused_rounds: Optional[bool] = None,
+                 devices: Optional[Sequence[jax.Device]] = None,
                  hw: HardwareModel = DEFAULT_HW,
                  sampler: Callable = greedy):
         self.cfg = cfg
@@ -119,7 +124,8 @@ class ServingEngine:
                                      host_cache_blocks=host_cache_blocks,
                                      ssd_cache_blocks=ssd_cache_blocks,
                                      prefill_chunk_tokens=prefill_chunk_tokens,
-                                     fused_rounds=fused_rounds)
+                                     fused_rounds=fused_rounds,
+                                     devices=devices)
         # rid -> modeled clock of its last emitted token (inter-token SLO)
         self._emit_clock: Dict[int, float] = {}
 
@@ -467,7 +473,7 @@ class ServingEngine:
                 self._round_passes += 1
                 report.steps_executed += len(chunk)
                 for r in chunk:
-                    self._finish_prefill_step(r, out[r.rid], sched)
+                    self._finish_prefill_step(r, out[r.rid], sched, report)
             for r in rest:
                 while True:
                     try:
@@ -477,15 +483,17 @@ class ServingEngine:
                         self._preempt_victim_or_raise(sched, report)
                 self._round_passes += 1
                 report.steps_executed += 1
-                self._finish_prefill_step(r, logits, sched)
+                self._finish_prefill_step(r, logits, sched, report)
         except RuntimeError:
             self._recover_fused(sched, report)
             return False
         return True
 
-    def _finish_prefill_step(self, r: Request, logits, sched) -> None:
+    def _finish_prefill_step(self, r: Request, logits, sched,
+                             report: EngineReport) -> None:
         if logits is None:
             return              # prefill still in flight
+        report.first_token_logits[r.rid] = np.asarray(logits[0])
         tok = self.sampler(logits, 0)
         self._emit(_SingleSeq(r), tok, 0)
         sched.next_step[r.rid] = 1
@@ -571,6 +579,7 @@ class ServingEngine:
             report.steps_executed += 1
             if logits is None:
                 return                   # prefill still in flight
+            report.first_token_logits[r.rid] = np.asarray(logits[0])
             tok = self.sampler(logits, 0)
         else:
             last = np.asarray([r.tokens[i - 1]], np.int32)
@@ -602,6 +611,8 @@ class ServingEngine:
         if mb.next_step == 0:
             tokens = jnp.asarray(mb.batch_prompts())
             logits = cl.prefill_mb(mb.mb, tokens, mb.n_new)
+            for b, r in enumerate(mb.requests):
+                report.first_token_logits[r.rid] = np.asarray(logits[b])
             tok = self.sampler(logits, 0)
             self._emit(mb, tok, 0)
             mb.next_step = 1

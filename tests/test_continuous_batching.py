@@ -208,3 +208,39 @@ def test_paged_repartition_streams_blocks(static_report):
     assert len(cl.token_group) == 3
     for r in reqs:
         assert toks[r.rid] == static_report.tokens[r.rid][:4]
+
+
+def test_first_token_logits_match_reference_forward(continuous_report,
+                                                    static_report):
+    """Both serving loops expose the logits each request's first token was
+    sampled from; at float32 they agree with one unpipelined prefill."""
+    for rep in (continuous_report, static_report):
+        assert sorted(rep.first_token_logits) == list(range(len(PLENS)))
+        for rid, prompt in enumerate(PROMPTS):
+            want = MODEL.prefill(PARAMS, {"tokens": prompt[None]})[0][0]
+            np.testing.assert_allclose(rep.first_token_logits[rid],
+                                       np.asarray(want), rtol=1e-5,
+                                       atol=1e-5)
+            assert int(np.argmax(rep.first_token_logits[rid])) == \
+                rep.tokens[rid][0]
+
+
+def test_stages_run_on_their_devices_and_share_compiles():
+    """Each stage's parameters sit on the device the cluster gave it, the
+    cluster keeps the full weights in host memory, and a second engine over
+    the same model reuses the first one's compiled stage functions."""
+    dev = jax.local_devices()[0]
+    eng = ServingEngine(CFG, MODEL, PARAMS, 2, paged=True, devices=[dev])
+    assert all(isinstance(a, np.ndarray)
+               for a in jax.tree.leaves(eng.cluster.params))
+    for w in eng.cluster.token_group:
+        assert w.device == dev
+        assert {d for a in jax.tree.leaves(w.sp) for d in a.devices()} == {dev}
+    first = eng.run_continuous(mkreqs(2), max_active=2)
+    jits = MODEL.stage_jits(first=True, last=False)
+    sizes = {k: f._cache_size() for k, f in jits.items()}
+    again = ServingEngine(CFG, MODEL, PARAMS, 2, paged=True, devices=[dev])
+    assert again.cluster.token_group[0]._decode_batch is jits["decode_batch"]
+    second = again.run_continuous(mkreqs(2), max_active=2)
+    assert second.tokens == first.tokens
+    assert {k: f._cache_size() for k, f in jits.items()} == sizes

@@ -292,3 +292,16 @@ def test_ssd_decode_step_matches_sequential():
         y, h = ssd_decode_step(x[:, t], dt[:, t], a_neg, bm[:, t], cm[:, t], h)
     np.testing.assert_allclose(y, y_ref[:, -1], rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(h, h_ref, rtol=1e-5, atol=1e-5)
+
+
+def test_interpret_mode_follows_the_backend(monkeypatch):
+    """Kernels run interpreted on the CPU, compiled on a TPU, and refuse any
+    other backend instead of interpreting there; the choice is made per
+    call, so importing the kernels touches no backend."""
+    from repro.kernels import ops
+    assert ops.interpret_mode() is (jax.default_backend() == "cpu")
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert ops.interpret_mode() is False
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="'gpu'"):
+        ops.interpret_mode()

@@ -57,12 +57,7 @@ def test_jit_under_local_mesh_with_rules():
     b_sh = batch_shardings(
         jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), batch),
         cfg, mesh)
-    # jax >= 0.5 wants an explicit mesh context; 0.4.x has no jax.set_mesh and
-    # NamedSharding already carries the mesh, so the context is optional
-    import contextlib
-    set_mesh = getattr(jax, "set_mesh", None)
-    ctx = set_mesh(mesh) if set_mesh is not None else contextlib.nullcontext()
-    with ctx:
+    with jax.set_mesh(mesh):
         loss = jax.jit(model.loss, in_shardings=(sh, b_sh))(params, batch)
     assert bool(jnp.isfinite(loss))
 
@@ -80,3 +75,20 @@ def test_supports_shape_matrix():
                 assert sname == "long_500k"
                 assert get_arch(name).family not in ("ssm", "hybrid")
     assert runnable == 32 and skipped == 8      # 40 total cells
+
+
+def test_logical_constraint_needs_a_mesh_and_raises_inside_one():
+    """Installed rules are a no-op with no mesh active; inside a mesh a
+    constraint the mesh cannot satisfy raises instead of being dropped."""
+    from repro.models import common
+    from repro.models.common import logical_constraint, set_logical_rules
+    x = jnp.ones((4, 8))
+    prev = common._LOGICAL_RULES
+    set_logical_rules({"batch": "nope"})
+    try:
+        assert logical_constraint(x, "batch", None) is x
+        with jax.set_mesh(make_local_mesh()):
+            with pytest.raises(Exception, match="nope"):
+                jax.jit(lambda a: logical_constraint(a, "batch", None))(x)
+    finally:
+        set_logical_rules(prev)
