@@ -97,8 +97,9 @@ def _export_trace_artifacts(rcfg, model, params, prompts) -> None:
     ``repro.trace/v1`` dump plus its Perfetto and Prometheus renderings
     into ``$BENCH_JSON_DIR``.  CI uploads these as workflow artifacts and
     gates ``tools/trace_report.py --assert`` on the dump; without
-    ``BENCH_JSON_DIR`` only the coverage row is emitted."""
-    tracer = tracing.Tracer()
+    ``BENCH_JSON_DIR`` only the coverage row is emitted.  The tracer reads
+    the modeled clock, which the critical-path replay assumes."""
+    tracer = tracing.Tracer(clock=tracing.modeled_ns)
     prev_trace = tracing.install(tracer)
     tele = telemetry.Telemetry()
     prev_tele = telemetry.install(tele)

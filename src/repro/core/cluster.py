@@ -440,7 +440,7 @@ class DejaVuCluster:
         if self.mode == "disaggregated":
             self._stream_prompt_blocks(rid, plen)
         if self.replication:
-            self._replicate_paged(rid, step=0)
+            self._replicate_seqs([(rid, 0, None)])
         if self.swapping:
             for w in self.token_group:
                 w.paged_offload(rid)
@@ -530,7 +530,7 @@ class DejaVuCluster:
                                           new_tokens=1, microbatch=1),
                 self.cfg.num_layers, 8, pos + 1, self.hw))
         if self.replication:
-            self._replicate_paged(rid, step=step, pos=pos)
+            self._replicate_seqs([(rid, step, pos)])
         if self.swapping:
             for w in self.token_group:
                 w.paged_offload(rid)
@@ -556,39 +556,41 @@ class DejaVuCluster:
         tokens: [B] int32 (each sequence's last sampled token); steps:
         per-sequence 1-based decode step.  Returns logits [B,V]."""
         poses = [self.seq_len[rid] for rid in rids]
-        with telemetry.span("pass", kind="fused_decode"), \
-                tracing.span("pass", kind="fused_decode", rids=list(rids)):
+        # the trace's pass covers the pass's replication too, as the time
+        # between the step and its sampling does
+        with tracing.span("pass", kind="fused_decode", rids=rids):
+            with telemetry.span("pass", kind="fused_decode"):
+                if self.swapping:
+                    for w in self.token_group:
+                        for rid in rids:
+                            w.paged_restore(rid)
+                for w in self.token_group:
+                    need = sum(1 for rid in rids
+                               if w.pool.append_needs_block(rid))
+                    if need > w.pool.num_free():
+                        raise PoolExhausted(
+                            f"worker {w.wid} pool cannot absorb a fused "
+                            f"round of {len(rids)} appends ({need} needed, "
+                            f"{w.pool.num_free()} free)")
+                x = np.asarray(tokens, np.int32)
+                for w in self.token_group:
+                    x = w.decode_paged_batch(rids, w.put(x), poses)
+                for rid, pos in zip(rids, poses):
+                    self.seq_len[rid] = pos + 1
+                    self._register_compute(1, pos + 1)
+                ctx = max(1, (sum(poses) + len(poses)) // max(len(poses), 1))
+                telemetry.advance(cm.decode_round_time(
+                    self.cfg, len(rids), ctx, self.cfg.num_layers, 8,
+                    self.hw, fused=True))
+            if self.replication:
+                self._replicate_seqs(list(zip(rids, steps, poses)))
             if self.swapping:
                 for w in self.token_group:
                     for rid in rids:
-                        w.paged_restore(rid)
-            for w in self.token_group:
-                need = sum(1 for rid in rids if w.pool.append_needs_block(rid))
-                if need > w.pool.num_free():
-                    raise PoolExhausted(
-                        f"worker {w.wid} pool cannot absorb a fused round of "
-                        f"{len(rids)} appends ({need} needed, "
-                        f"{w.pool.num_free()} free)")
-            x = np.asarray(tokens, np.int32)
-            for w in self.token_group:
-                x = w.decode_paged_batch(rids, w.put(x), poses)
-            for rid, pos in zip(rids, poses):
-                self.seq_len[rid] = pos + 1
-                self._register_compute(1, pos + 1)
-            ctx = max(1, (sum(poses) + len(poses)) // max(len(poses), 1))
-            telemetry.advance(cm.decode_round_time(
-                self.cfg, len(rids), ctx, self.cfg.num_layers, 8, self.hw,
-                fused=True))
-        if self.replication:
-            for rid, step, pos in zip(rids, steps, poses):
-                self._replicate_paged(rid, step=step, pos=pos)
-        if self.swapping:
-            for w in self.token_group:
-                for rid in rids:
-                    w.paged_offload(rid)
-        for w in set(self.prompt_group + self.token_group):
-            w.heartbeat()
-        self._track_kv_peak()
+                        w.paged_offload(rid)
+            for w in set(self.prompt_group + self.token_group):
+                w.heartbeat()
+            self._track_kv_peak()
         return x
 
     def prefill_chunkset_pass(self, rids: List[int]
@@ -601,7 +603,7 @@ class DejaVuCluster:
         prefill_logits | None}; a completed prompt runs the same post-prefill
         streaming / replication / swap as the per-sequence path."""
         with telemetry.span("pass", kind="chunkset"), \
-                tracing.span("pass", kind="chunkset", rids=list(rids)):
+                tracing.span("pass", kind="chunkset", rids=rids):
             return self._prefill_chunkset_pass(rids)
 
     def _prefill_chunkset_pass(self, rids: List[int]
@@ -629,25 +631,40 @@ class DejaVuCluster:
                 out[rid] = self._finish_prefill(rid)
         return out
 
+    def _replicate_seqs(self, items: List[Tuple[int, int, Optional[int]]]
+                        ) -> None:
+        """`_replicate_paged` for each (rid, step, pos), as one
+        ``dejavulib.replicate`` span with the bytes pushed."""
+        with tracing.span("dejavulib.replicate", n=len(items)) as sp:
+            pushed = sum(self._replicate_paged(rid, step, pos)
+                         for rid, step, pos in items)
+            if sp is not None:
+                sp["bytes"] = pushed
+
     def _replicate_paged(self, rid: int, step: int,
-                         pos: Optional[int] = None) -> None:
+                         pos: Optional[int] = None) -> int:
         """Ring-replicate at BLOCK granularity: prefill pushes every live
-        block, a decode step pushes only the block it touched."""
+        block, a decode step pushes only the block it touched.  Waits for
+        the streams; returns the bytes pushed."""
         group = self.token_group
         n = len(group)
+        pushed = 0
         for i, w in enumerate(group):
             if rid not in w.pool.tables:
                 continue
             peer = group[(i + 1) % n]
             if pos is None:
                 for j, arrays in w.live_blocks(rid).items():
-                    w.cache.replicate_block_to(peer.cache, rid, j, arrays,
-                                               step, self.controller.ack_replication)
+                    pushed += w.cache.replicate_block_to(
+                        peer.cache, rid, j, arrays, step,
+                        self.controller.ack_replication)
             else:
                 j, arrays = w.touched_block(rid, pos)
-                w.cache.replicate_block_to(peer.cache, rid, j, arrays, step,
-                                           self.controller.ack_replication)
+                pushed += w.cache.replicate_block_to(
+                    peer.cache, rid, j, arrays, step,
+                    self.controller.ack_replication)
         self.streamer.drain()
+        return pushed
 
     def preempt_seq(self, rid: int) -> None:
         """Swap a running sequence fully out (block-granular) to free pool

@@ -1,6 +1,6 @@
 """Wire-format exporters for telemetry snapshots and trace dumps.
 
-Three formats, all pure functions over the versioned snapshot dicts so
+Two formats, both pure functions over the versioned snapshot dicts so
 they can run offline over archived JSON as well as live registries:
 
 - :func:`telemetry_to_prometheus` — Prometheus text exposition
@@ -9,9 +9,6 @@ they can run offline over archived JSON as well as live registries:
   ``repro.trace/v1`` dump; loads directly in https://ui.perfetto.dev
   with one named thread-track per recorder track and instant events for
   faults.
-- :func:`trace_to_otlp` — OTLP-JSON (``ExportTraceServiceRequest``
-  shape: resourceSpans → scopeSpans → spans) for the same dump, with
-  requests mapped to trace IDs and causal parents to ``parentSpanId``.
 
 All output is deterministic: keys sorted, label sets sorted, tracks in
 snapshot order (which is itself sorted).
@@ -20,7 +17,7 @@ from __future__ import annotations
 
 import json
 import re
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Tuple
 
 from repro.core import telemetry as _telemetry
 from repro.core import tracing as _tracing
@@ -150,7 +147,7 @@ def telemetry_to_prometheus(snapshot: Dict[str, object]) -> str:
 # ---------------------------------------------------------------------------
 
 def _track_order(tracks: Dict[str, object]) -> List[str]:
-    """serve first, streamer last, worker/stage tracks in between sorted."""
+    """serve first, streamer last, any other track in between, sorted."""
     names = list(tracks)
     def rank(n: str) -> Tuple[int, str]:
         if n == _tracing.SERVE_TRACK:
@@ -204,86 +201,6 @@ def trace_to_perfetto(trace: Dict[str, object]) -> Dict[str, object]:
                            "pid": pid, "tid": tid, "ts": 0.0,
                            "args": {"dropped": tr["dropped"]}})
     return {"traceEvents": events, "displayTimeUnit": "ms"}
-
-
-# ---------------------------------------------------------------------------
-# OTLP-JSON spans
-# ---------------------------------------------------------------------------
-
-def _otlp_attr(k: str, v: object) -> dict:
-    if isinstance(v, bool):
-        val = {"boolValue": v}
-    elif isinstance(v, int):
-        val = {"intValue": str(v)}
-    elif isinstance(v, float):
-        val = {"doubleValue": v}
-    else:
-        val = {"stringValue": str(v)}
-    return {"key": k, "value": val}
-
-
-def _trace_id(rid: Optional[int]) -> str:
-    # one trace per request; rid-less events share the run-level trace 0
-    return format(0 if rid is None else int(rid) + 1, "032x")
-
-
-def _span_id(track_idx: int, eid: int) -> str:
-    return format(((track_idx + 1) << 40) | (eid + 1), "016x")
-
-
-def trace_to_otlp(trace: Dict[str, object],
-                  service_name: str = "dejavu-repro") -> Dict[str, object]:
-    """Convert a ``repro.trace/v1`` dump to an OTLP-JSON
-    ``ExportTraceServiceRequest`` document.
-
-    Each request ID becomes its own 128-bit trace ID (rid-less events
-    share trace 0); span IDs encode (track, eid) so causal ``parent``
-    links resolve to ``parentSpanId`` within the serve track.  Instant
-    events export as zero-length spans, which every OTLP backend
-    accepts.
-    """
-    schema = trace.get("schema")
-    if schema != _tracing.SCHEMA:
-        raise ValueError(f"expected {_tracing.SCHEMA} dump, got {schema!r}")
-    tracks = trace.get("tracks", {})
-    order = _track_order(tracks)
-    # `parent` eids always reference the serve track (spans live there)
-    serve_ti = order.index(_tracing.SERVE_TRACK) if _tracing.SERVE_TRACK in order else 0
-    spans: List[dict] = []
-    for ti, tname in enumerate(order):
-        tr = tracks[tname]
-        for ev in tr["events"]:
-            rid = ev.get("rid")
-            start = int(ev["ts"])
-            end = start + int(ev.get("dur", 0))
-            attrs = [_otlp_attr("track", tname)]
-            if "seq" in ev:
-                attrs.append(_otlp_attr("seq", ev["seq"]))
-            for k in sorted(ev.get("args", {})):
-                attrs.append(_otlp_attr(k, ev["args"][k]))
-            span = {
-                "traceId": _trace_id(rid),
-                "spanId": _span_id(ti, ev["eid"]),
-                "name": ev["name"],
-                "kind": 1,  # SPAN_KIND_INTERNAL
-                "startTimeUnixNano": str(start),
-                "endTimeUnixNano": str(end),
-                "attributes": attrs,
-            }
-            if ev.get("parent") is not None:
-                span["parentSpanId"] = _span_id(serve_ti, ev["parent"])
-            spans.append(span)
-    return {
-        "resourceSpans": [{
-            "resource": {"attributes": [
-                _otlp_attr("service.name", service_name),
-            ]},
-            "scopeSpans": [{
-                "scope": {"name": "repro.tracing", "version": "1"},
-                "spans": spans,
-            }],
-        }],
-    }
 
 
 def dumps(doc: Dict[str, object]) -> str:

@@ -1,4 +1,4 @@
-"""Per-request causal tracing: a bounded, deterministic flight recorder.
+"""Per-request causal tracing: a bounded flight recorder on one clock.
 
 The telemetry registry (`repro.core.telemetry`) deliberately collapses
 spans to path-keyed aggregates and never keeps individual events, so it
@@ -6,30 +6,51 @@ can answer "what is TTFT p99?" but not "why did request 17's TTFT hit
 p99?".  This module is the complementary layer: it records *individual*
 events and spans with causal parent links and request/sequence IDs, so a
 single run can be replayed into a per-request critical-path breakdown
-(`tools/trace_report.py`) or exported to Perfetto / OTLP wire formats
-(`repro.core.exporters`).
+(`tools/trace_report.py`), exported to Perfetto (`repro.core.exporters`),
+or split by layer beside a device trace.
 
-Design constraints, matching PR 7's telemetry rules:
+**The clock.**  A `Tracer` reads one clock, chosen at construction, in
+integer nanoseconds:
 
-1. **Determinism.**  Two identical runs produce byte-identical dumps.
-   Timestamps are *integer nanoseconds on the modeled clock* (the
-   telemetry registry's `clock_s`, read only from the serving thread).
-   Events fired from other threads (the DejaVuLib streamer) never read
-   the clock: they land on their own *track*, where each event's
-   timestamp is the track's running cursor (the accumulated modeled
-   duration of the events before it) — the streamer FIFO serializes its
-   tasks, so per-track order and cursors are reproducible.  The dump
-   keeps each track's own order and never merges across tracks.
+* ``host_ns`` (the default, ``time.perf_counter_ns``): what the program
+  really spends.  It is the clock of `time.perf_counter`, which the
+  on-chip benchmark's recorder reads, so spans line up with its passes
+  with no conversion.  Every thread reads it: an event from the DejaVuLib
+  streamer thread is stamped with the host time it fires.  A caller's
+  ``dur_ns`` is a modeled duration, so on this clock it is kept as the
+  argument ``model_ns`` and the event is an instant.
+* ``modeled_ns``: the telemetry registry's ``clock_s``, which
+  ``telemetry.advance(cm.*)`` moves — the CPU cost model's clock, for the
+  callers that replay it (`benchmarks/failures.py` and its
+  ``trace_report --assert`` gate).  It is read only on the serving
+  thread.  Events fired from other threads never read it: they land on
+  their own *track*, where each event's timestamp is the track's running
+  cursor (the accumulated modeled duration of the events before it) —
+  the streamer FIFO serializes its tasks, so per-track order and cursors
+  are reproducible.
+
+Guarantees:
+
+1. **Determinism, on the modeled clock.**  Two identical runs produce
+   byte-identical dumps; the dump keeps each track's own order and never
+   merges across tracks.  On the host clock names, nesting, per-track
+   order and arguments repeat; times do not.
 2. **Near-free when disabled.**  Call sites use the module helpers
    (`event`, `span`, `active`), a single ``is None`` check when no
    tracer is installed — the same pattern as `telemetry` and
    `dejavulib.faults` (micro-benchmarked in
-   ``benchmarks/streaming_breakdown.py``).
+   ``benchmarks/streaming_breakdown.py``).  Call sites build costly
+   arguments (byte sums, rid lists) only when `active()`.
 3. **Bounded memory, no silent truncation.**  Each track is a
    fixed-capacity ring buffer that overwrites its oldest events
    (flight-recorder semantics); the snapshot reports explicit
    ``dropped`` and ``emitted`` counters per track so a truncated dump
    is always visibly truncated.
+4. **One clock with the device trace.**  Every span also opens a
+   ``jax.profiler.TraceAnnotation`` named ``repro.<span name>``: while
+   the JAX profiler runs, each span lands on the host plane of its
+   ``.xplane.pb`` on the device trace's own clock, whichever clock the
+   tracer reads.
 
 Cross-thread rules: span open/close happens on the owner (serving)
 thread only — `Tracer.span` raises off-thread, mirroring the telemetry
@@ -55,8 +76,11 @@ from __future__ import annotations
 
 import json
 import threading
+import time
 from contextlib import contextmanager
-from typing import Dict, Iterator, List, Optional
+from typing import Callable, Dict, Iterator, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 from repro.core import telemetry
 
@@ -67,6 +91,20 @@ SCHEMA = "repro.trace/v1"
 DEFAULT_CAPACITY = 1 << 16
 
 _NS = 1_000_000_000
+
+#: the prefix of every span's profiler annotation
+ANNOTATION_PREFIX = "repro."
+
+#: the host clock (the default)
+host_ns: Callable[[], int] = time.perf_counter_ns
+
+
+def modeled_ns() -> int:
+    """The cost model's clock: the installed telemetry registry's
+    ``clock_s`` (0 without one).  Serving thread only."""
+    t = telemetry.current()
+    return 0 if t is None else int(round(t.clock_s * _NS))
+
 
 #: the track serving-thread events land on by default
 SERVE_TRACK = "serve"
@@ -109,10 +147,14 @@ class Tracer:
     One tracer == one run (or one aggregation window).  All mutation is
     lock-protected; the owner thread is bound at construction and
     re-bound by :func:`install`, exactly like the telemetry registry.
+    `clock` is ``host_ns`` (default) or ``modeled_ns`` (module docstring).
     """
 
-    def __init__(self, capacity: int = DEFAULT_CAPACITY):
+    def __init__(self, capacity: int = DEFAULT_CAPACITY,
+                 clock: Callable[[], int] = host_ns):
         self.capacity = capacity
+        self.clock = clock
+        self.modeled = clock is modeled_ns
         self._lock = threading.Lock()
         self._tracks: Dict[str, _Track] = {}
         self._tls = threading.local()
@@ -131,10 +173,6 @@ class Tracer:
             st = self._tls.stack = []
         return st
 
-    def _now_ns(self) -> int:
-        t = telemetry.current()
-        return 0 if t is None else int(round(t.clock_s * _NS))
-
     @staticmethod
     def _mkev(eid: int, name: str, ph: str, ts: int, dur: int,
               parent: Optional[int], rid: Optional[int],
@@ -149,7 +187,9 @@ class Tracer:
         if seq is not None:
             ev["seq"] = int(seq)
         if args:
-            ev["args"] = {k: args[k] for k in sorted(args)}
+            # lists are copied: a caller may reuse the one it passed
+            ev["args"] = {k: list(v) if isinstance(v, (list, tuple)) else v
+                          for k, v in sorted(args.items())}
         return ev
 
     # -- recording -----------------------------------------------------
@@ -159,13 +199,15 @@ class Tracer:
               **args: object) -> None:
         """Record one instant (or pre-timed) event.
 
-        Thread routing: on the owner (serving) thread the timestamp is
-        the modeled clock and the event lands on `track` (default
-        ``serve``) with the current span as causal parent.  On any other
-        thread the clock is never read: the event lands on the
-        ``streamer`` track (unless `track` is given) at the track's
-        running cursor, which then advances by `dur_ns` — callers on
-        such threads carry their own modeled durations.
+        Thread routing: on the owner (serving) thread the event lands on
+        `track` (default ``serve``) with the current span as causal
+        parent; on any other thread on the ``streamer`` track (unless
+        `track` is given).  `dur_ns` is the caller's modeled duration.
+        On the host clock an untimed event is an instant at the clock's
+        reading, with `dur_ns` kept as the argument ``model_ns``.  On the
+        modeled clock the owner thread reads it; another thread never
+        does: its event sits at the track's running cursor, which then
+        advances by `dur_ns`.
         """
         on_owner = threading.get_ident() == self._owner
         if track is None:
@@ -175,11 +217,16 @@ class Tracer:
             st = self._stack()
             if st:
                 parent = st[-1]
+        if ts_ns is None and not self.modeled:
+            ts_ns = self.clock()
+            if dur_ns:
+                args["model_ns"] = int(dur_ns)
+                dur_ns = 0
         with self._lock:
             tr = self._track(track)
-            if ts_ns is None:
+            if ts_ns is None:                # the modeled clock
                 if on_owner:
-                    ts_ns = self._now_ns()
+                    ts_ns = self.clock()
                 else:
                     ts_ns = tr.cursor_ns
                     tr.cursor_ns += int(dur_ns)
@@ -191,10 +238,13 @@ class Tracer:
 
     @contextmanager
     def span(self, name: str, *, rid: Optional[int] = None,
-             seq: Optional[int] = None, **args: object) -> Iterator[None]:
-        """A complete ("X") event timed on the modeled clock, recorded at
-        close.  Owner thread only (the clock lives there); the eid is
-        reserved at open so children recorded inside link to it."""
+             seq: Optional[int] = None,
+             **args: object) -> Iterator[Dict[str, object]]:
+        """A complete ("X") event on the tracer's clock, recorded at
+        close, inside a profiler annotation ``repro.<name>``.  Owner
+        thread only; the eid is reserved at open so children recorded
+        inside link to it.  Yields the span's arguments, which the body
+        may add to (a byte count known only at the end)."""
         if threading.get_ident() != self._owner:
             raise RuntimeError(
                 "Tracer.span: spans open/close on the owner (serving) "
@@ -206,15 +256,16 @@ class Tracer:
             eid = tr.next_eid
             tr.next_eid += 1
         st.append(eid)
-        t0 = self._now_ns()
-        try:
-            yield
-        finally:
-            st.pop()
-            dur = self._now_ns() - t0
-            with self._lock:
-                tr.append(self._mkev(eid, name, "X", t0, dur, parent,
-                                     rid, seq, args))
+        with TraceAnnotation(ANNOTATION_PREFIX + name):
+            t0 = self.clock()
+            try:
+                yield args
+            finally:
+                st.pop()
+                dur = self.clock() - t0
+                with self._lock:
+                    tr.append(self._mkev(eid, name, "X", t0, dur, parent,
+                                         rid, seq, args))
 
     # -- snapshot ------------------------------------------------------
     def snapshot(self) -> Dict[str, object]:
@@ -275,10 +326,12 @@ def event(name: str, **kw: object) -> None:
 
 
 @contextmanager
-def span(name: str, **kw: object) -> Iterator[None]:
+def span(name: str, **kw: object) -> Iterator[Optional[Dict[str, object]]]:
+    """`Tracer.span` on the installed tracer; yields its arguments, or
+    None when no tracer is installed (nothing recorded or annotated)."""
     t = _ACTIVE
     if t is None:
-        yield
+        yield None
     else:
-        with t.span(name, **kw):
-            yield
+        with t.span(name, **kw) as args:
+            yield args

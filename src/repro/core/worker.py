@@ -136,10 +136,11 @@ class CacheManager:
     # --- paged-mode movement (block granularity) ------------------------
     def replicate_block_to(self, peer: "CacheManager", seq: int, j: int,
                            arrays: Dict[str, np.ndarray], step: int,
-                           ack_cb) -> None:
+                           ack_cb) -> int:
         """Stream ONE live KV block to the ring successor's replica store.
         Only the block touched this step crosses the wire (vs the dense
-        path's token-window of a padded cache)."""
+        path's token-window of a padded cache).  Returns the block's bytes
+        (before any compression)."""
         def _send():
             nbytes = 0
             for leaf, arr in arrays.items():
@@ -162,6 +163,7 @@ class CacheManager:
         model_s = self.net.model_time(raw // 2 if self.compress_replicas else raw)
         self.streamer.submit(_send, model_seconds=model_s,
                              tag=f"rep-w{self.wid}-seq{seq}-blk{j}-s{step}")
+        return raw
 
     def replica_blocks(self, wid: int, seq: int) -> Dict[int, Dict[str, np.ndarray]]:
         """All replica blocks this store holds for (failed worker, seq)."""
@@ -282,20 +284,26 @@ class StageWorker:
         if self.tier is not None:
             self.tier.on_host_failure()
 
-    def _check(self, op: Optional[str] = None, **ids: int):
+    def _check(self):
         if not self.alive:
             raise RuntimeError(f"worker {self.wid} is dead")
         # every stage op (prefill/decode, paged or not) passes through here
         telemetry.count("worker.stage_calls", 1, wid=self.wid)
-        if op is not None and tracing.active():
-            # per-stage timeline: one track per worker, instants at the
-            # modeled clock of the enclosing pass span
-            tracing.event(f"stage.{op}", track=f"w{self.wid}", **ids)
+
+    # A stage op's host layers are `tracing` spans tagged with `wid`:
+    # worker.gather / upload / stage / readback, kvpool.append /
+    # write_window (docs/observability.md, "Spans of the served path").
+    def _stage(self, fn, *args):
+        """The jitted stage call.  Its span times dispatch only: JAX
+        returns before the device finishes, and the host waits for it in
+        the next read-back."""
+        with tracing.span("worker.stage", wid=self.wid):
+            return fn(self.sp, *args)
 
     # ------------------------------------------------------------------
     def prefill(self, mb: int, x_or_tokens, max_len: int):
-        self._check("prefill", mb=mb)
-        x, ks, vs = self._prefill(self.sp, x_or_tokens)
+        self._check()
+        x, ks, vs = self._stage(self._prefill, x_or_tokens)
         kc = jnp.zeros(ks.shape[:2] + (max_len,) + ks.shape[3:], ks.dtype,
                        device=self.device)
         vc = jnp.zeros_like(kc)
@@ -305,10 +313,10 @@ class StageWorker:
         return x
 
     def decode(self, mb: int, x_or_token, pos: int):
-        self._check("decode", mb=mb)
+        self._check()
         slot = self.kv[mb]
-        x, kc, vc = self._decode(self.sp, x_or_token, slot["k"], slot["v"],
-                                 jnp.int32(pos))
+        x, kc, vc = self._stage(self._decode, x_or_token, slot["k"],
+                                slot["v"], jnp.int32(pos))
         self.kv[mb] = {"k": kc, "v": vc}
         return x
 
@@ -356,8 +364,8 @@ class StageWorker:
     def prefill_paged(self, seq: int, x_or_tokens, token_ids=None):
         """Stage prefill for ONE request (batch 1); KV lands in pool blocks.
         `token_ids` enables prefix-sharing of full prompt blocks."""
-        self._check("prefill_paged", rid=seq)
-        x, ks, vs = self._prefill(self.sp, x_or_tokens)
+        self._check()
+        x, ks, vs = self._stage(self._prefill, x_or_tokens)
         s = ks.shape[2]
         _, fresh = self.pool.allocate(seq, s, token_ids=token_ids)
         # shared blocks already hold identical data (same prefix, same
@@ -403,26 +411,25 @@ class StageWorker:
         scatter the chunk's K/V window back into its pages through kv_pack
         (DMA-aligned; the re-written head tokens of the aligned window hold
         identical values).  Requires `ensure_prefill_table` first."""
-        self._check("prefill_chunk", rid=seq)
+        self._check()
         c = int(x_or_tokens.shape[1])
-        pad_to = len(self.pool.tables[seq]) * self.pool.block_size
-        dense = self.pages.gather_dense(seq, pad_to)
-        x, kc, vc = self._prefill_chunk(self.sp, x_or_tokens,
-                                        self.put(dense["k"]),
-                                        self.put(dense["v"]),
-                                        jnp.int32(pos0))
-        self._write_chunk_window(seq, kc, vc, pos0, c, pad_to)
+        kc, vc, pad_to = self._gather_batch([seq])
+        x, kc, vc = self._stage(self._prefill_chunk, x_or_tokens, kc, vc,
+                                jnp.int32(pos0))
+        with tracing.span("worker.readback", wid=self.wid) as sp:
+            win = self._chunk_window(kc, vc, pos0, c, pad_to)
+            if sp is not None:
+                sp["bytes"] = win[0]["k"].nbytes + win[0]["v"].nbytes
+        with tracing.span("kvpool.write_window", wid=self.wid, n=1):
+            self._write_chunk_window(seq, win, pos0, c)
         return x
 
-    def _write_chunk_window(self, seq: int, kc, vc, pos0: int, c: int,
-                            pad_to: int) -> None:
-        """Scatter one chunk's K/V window [pos0, pos0+c) back into `seq`'s
-        pages through a DMA-aligned kv_pack (kc/vc: [Lstage, 1, S, H, D]; the
-        re-written head tokens of the aligned window hold identical values).
-        Shared by the per-sequence and fused chunk paths so alignment and
-        dirty-block accounting can never drift between them."""
+    def _chunk_window(self, kc, vc, pos0: int, c: int, pad_to: int):
+        """One chunk's K/V window [pos0, pos0+c), DMA-aligned, off the
+        device through kv_pack (kc/vc: [Lstage, 1, S, H, D]; the re-written
+        head tokens of the aligned window hold identical values) ->
+        (window, aligned start)."""
         from repro.kernels import ops as kops
-        bs = self.pool.block_size
         tb = self.cache.token_block
         t0a = (pos0 // tb) * tb
         w = min(-(-(pos0 + c - t0a) // tb) * tb, pad_to - t0a)
@@ -432,9 +439,17 @@ class StageWorker:
         # of tb is a valid granularity)
         tbw = tb if w % tb == 0 else math.gcd(w, tb)
         kc, vc = self.put((kc, vc))          # the pack runs on this stage
-        win = {"k": np.asarray(kops.kv_pack_auto(kc, t0a, w, token_block=tbw))[:, 0],
-               "v": np.asarray(kops.kv_pack_auto(vc, t0a, w, token_block=tbw))[:, 0]}
-        self.pages.write_window(seq, win, t0a)
+        return ({"k": np.asarray(kops.kv_pack_auto(kc, t0a, w, token_block=tbw))[:, 0],
+                 "v": np.asarray(kops.kv_pack_auto(vc, t0a, w, token_block=tbw))[:, 0]},
+                t0a)
+
+    def _write_chunk_window(self, seq: int, win, pos0: int, c: int) -> None:
+        """Scatter a `_chunk_window` back into `seq`'s pages.  Shared by the
+        per-sequence and fused chunk paths so alignment and dirty-block
+        accounting can never drift between them."""
+        arrays, t0a = win
+        bs = self.pool.block_size
+        self.pages.write_window(seq, arrays, t0a)
         self.paged_dirty.setdefault(seq, set()).update(
             range(t0a // bs, -(-(pos0 + c) // bs)))
 
@@ -442,27 +457,49 @@ class StageWorker:
         """One decode step for one sequence: append a slot (CoW if the tail
         block is shared), gather blocks -> dense stage cache, run the jitted
         stage, scatter the new token's K/V back into its block."""
-        self._check("decode_paged", rid=seq)
-        cow = self.pool.append(seq)
-        self.pages.apply_cow(cow)
-        pad_to = len(self.pool.tables[seq]) * self.pool.block_size
-        dense = self.pages.gather_dense(seq, pad_to)
-        x, kc, vc = self._decode(self.sp, x_or_token, self.put(dense["k"]),
-                                 self.put(dense["v"]), jnp.int32(pos))
-        win = {"k": np.asarray(kc[:, 0, pos:pos + 1]),
-               "v": np.asarray(vc[:, 0, pos:pos + 1])}
-        self.pages.write_window(seq, win, pos)
-        self.paged_dirty.setdefault(seq, set()).add(pos // self.pool.block_size)
+        self._check()
+        self._append_slots([seq])
+        kc, vc, _ = self._gather_batch([seq])
+        x, kc, vc = self._stage(self._decode, x_or_token, kc, vc,
+                                jnp.int32(pos))
+        with tracing.span("worker.readback", wid=self.wid) as sp:
+            win = {"k": np.asarray(kc[:, 0, pos:pos + 1]),
+                   "v": np.asarray(vc[:, 0, pos:pos + 1])}
+            if sp is not None:
+                sp["bytes"] = win["k"].nbytes + win["v"].nbytes
+        with tracing.span("kvpool.write_window", wid=self.wid, n=1):
+            self.pages.write_window(seq, win, pos)
+            self.paged_dirty.setdefault(seq, set()).add(
+                pos // self.pool.block_size)
         return x
+
+    def _append_slots(self, seqs) -> None:
+        """One new slot per sequence (CoW where its tail block is shared)."""
+        with tracing.span("kvpool.append", wid=self.wid, n=len(seqs)):
+            for seq in seqs:
+                cow = self.pool.append(seq)
+                self.pages.apply_cow(cow)
 
     def _gather_batch(self, seqs) -> Tuple[jax.Array, jax.Array, int]:
         """Densify every sequence's pages to a common pad (ragged lengths
-        over per-sequence block tables) -> (kc, vc, pad_to) with kc/vc
-        [Lstage, B, pad_to, H, D] — the fused-round stage-cache layout."""
+        over per-sequence block tables) and upload -> (kc, vc, pad_to) with
+        kc/vc [Lstage, B, pad_to, H, D] — the fused-round stage-cache
+        layout."""
         pad_to = max(len(self.pool.tables[s]) for s in seqs) * self.pool.block_size
-        dense = [self.pages.gather_dense(s, pad_to) for s in seqs]
-        kc = self.put(np.concatenate([d["k"] for d in dense], axis=1))
-        vc = self.put(np.concatenate([d["v"] for d in dense], axis=1))
+        out = []
+        with tracing.span("worker.gather", wid=self.wid, n=len(seqs),
+                          pad_to=pad_to):
+            dense = [self.pages.gather_dense(s, pad_to) for s in seqs]
+            for leaf in ("k", "v"):
+                a = (dense[0][leaf] if len(dense) == 1 else
+                     np.concatenate([d[leaf] for d in dense], axis=1))
+                # uploaded before V is concatenated: device_put returns
+                # before its DMA, which overlaps that host work
+                with tracing.span("worker.upload", wid=self.wid) as sp:
+                    out.append(self.put(a))
+                    if sp is not None:
+                        sp["bytes"] = a.nbytes
+        kc, vc = out
         return kc, vc, pad_to
 
     def decode_paged_batch(self, seqs, x_or_tokens, poses):
@@ -473,36 +510,39 @@ class StageWorker:
         window back through one multi-sequence ragged buffered copy.  The
         cluster pre-flights pool capacity for the WHOLE batch first, so the
         per-sequence appends here cannot run out mid-batch."""
-        self._check("decode_batch", n=len(seqs))
+        self._check()
         from repro.kernels import ops as kops
         bs = self.pool.block_size
-        for seq in seqs:
-            cow = self.pool.append(seq)
-            self.pages.apply_cow(cow)
+        self._append_slots(seqs)
         kc, vc, pad_to = self._gather_batch(seqs)
-        pos = self.put(np.asarray(poses, np.int32))
-        x, kc, vc = self._decode_batch(self.sp, x_or_tokens, kc, vc, pos)
+        x, kc, vc = self._stage(self._decode_batch, x_or_tokens, kc, vc,
+                                self.put(np.asarray(poses, np.int32)))
         tb = self.cache.token_block
         t0s = [(p // tb) * tb for p in poses]
-        if pad_to % tb == 0:
-            # one ragged pack gathers every sequence's aligned one-token
-            # window (vs B separate kv_pack launches); the aligned head
-            # tokens re-write identical values, like the per-seq chunk path
-            starts = np.asarray(t0s, np.int32)
-            wk = np.asarray(kops.kv_pack_ragged_auto(kc, starts, tb,
-                                                     token_block=tb))
-            wv = np.asarray(kops.kv_pack_ragged_auto(vc, starts, tb,
-                                                     token_block=tb))
-            wins = [({"k": wk[:, i], "v": wv[:, i]}, t0s[i])
-                    for i in range(len(seqs))]
-        else:                            # unaligned pool blocks: plain slices
-            kc_np, vc_np = np.asarray(kc), np.asarray(vc)
-            wins = [({"k": kc_np[:, i, p:p + 1], "v": vc_np[:, i, p:p + 1]}, p)
-                    for i, p in enumerate(poses)]
-        for i, seq in enumerate(seqs):
-            win, t0 = wins[i]
-            self.pages.write_window(seq, win, t0)
-            self.paged_dirty.setdefault(seq, set()).add(poses[i] // bs)
+        with tracing.span("worker.readback", wid=self.wid) as sp:
+            if pad_to % tb == 0:
+                # one ragged pack gathers every sequence's aligned one-token
+                # window (vs B separate kv_pack launches); the aligned head
+                # tokens re-write identical values, like the per-seq chunk
+                # path
+                starts = np.asarray(t0s, np.int32)
+                k = np.asarray(kops.kv_pack_ragged_auto(kc, starts, tb,
+                                                        token_block=tb))
+                v = np.asarray(kops.kv_pack_ragged_auto(vc, starts, tb,
+                                                        token_block=tb))
+                wins = [({"k": k[:, i], "v": v[:, i]}, t0s[i])
+                        for i in range(len(seqs))]
+            else:                        # unaligned pool blocks: plain slices
+                k, v = np.asarray(kc), np.asarray(vc)
+                wins = [({"k": k[:, i, p:p + 1], "v": v[:, i, p:p + 1]}, p)
+                        for i, p in enumerate(poses)]
+            if sp is not None:
+                sp["bytes"] = k.nbytes + v.nbytes
+        with tracing.span("kvpool.write_window", wid=self.wid, n=len(seqs)):
+            for i, seq in enumerate(seqs):
+                win, t0 = wins[i]
+                self.pages.write_window(seq, win, t0)
+                self.paged_dirty.setdefault(seq, set()).add(poses[i] // bs)
         return x
 
     def prefill_chunk_paged_batch(self, seqs, x_or_tokens, pos0s, q_lens):
@@ -512,16 +552,22 @@ class StageWorker:
         and attending over its own resident prefix plus itself.  Each
         sequence's K/V window scatters back into its own pages.  Requires
         `ensure_prefill_table` for every sequence first."""
-        self._check("chunkset", n=len(seqs))
+        self._check()
         kc, vc, pad_to = self._gather_batch(seqs)
-        pos = self.put(np.asarray(pos0s, np.int32))
-        ql = self.put(np.asarray(q_lens, np.int32))
-        x, kc, vc = self._prefill_chunk_batch(self.sp, x_or_tokens, kc, vc,
-                                              pos, ql)
-        kc_np, vc_np = np.asarray(kc), np.asarray(vc)
-        for i, seq in enumerate(seqs):
-            self._write_chunk_window(seq, kc_np[:, i:i + 1], vc_np[:, i:i + 1],
-                                     pos0s[i], q_lens[i], pad_to)
+        x, kc, vc = self._stage(self._prefill_chunk_batch, x_or_tokens, kc,
+                                vc, self.put(np.asarray(pos0s, np.int32)),
+                                self.put(np.asarray(q_lens, np.int32)))
+        with tracing.span("worker.readback", wid=self.wid) as sp:
+            kc_np, vc_np = np.asarray(kc), np.asarray(vc)
+            wins = [self._chunk_window(kc_np[:, i:i + 1], vc_np[:, i:i + 1],
+                                       pos0s[i], q_lens[i], pad_to)
+                    for i in range(len(seqs))]
+            if sp is not None:
+                sp["bytes"] = kc_np.nbytes + vc_np.nbytes + sum(
+                    w["k"].nbytes + w["v"].nbytes for w, _ in wins)
+        with tracing.span("kvpool.write_window", wid=self.wid, n=len(seqs)):
+            for i, seq in enumerate(seqs):
+                self._write_chunk_window(seq, wins[i], pos0s[i], q_lens[i])
         return x
 
     def touched_block(self, seq: int, pos: int):
@@ -639,7 +685,7 @@ class StageWorker:
         """Build `seq`'s prompt prefix from cached blocks: co-resident pool
         blocks are ref-shared; the rest are promoted out of the tier
         hierarchy.  Returns the number of tier-promoted blocks."""
-        self._check("adopt_prefix", rid=seq)
+        self._check()
         missing = [h for h in hashes if not self.pool.has_hash(h)]
         if len(missing) > self.pool.num_free():
             raise PoolExhausted(

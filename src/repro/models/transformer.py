@@ -219,16 +219,20 @@ class DecoderLM:
         the compiled executables instead of tracing and compiling its own
         (a stage on another device reuses the trace and compiles only for
         that device).  Stage 0 takes tokens where the others take
-        activations."""
+        activations.  Each program is named after its stage function
+        (``jit_stage_decode_batch``), so a device trace tells them apart."""
         key = (first, last)
         if key not in self._stage_jits:
             def stage(fn, token_kw):
                 if first:
-                    return jax.jit(lambda sp, tokens, *a: fn(
-                        sp, None, *a, first=True, last=last,
-                        **{token_kw: tokens}))
-                return jax.jit(lambda sp, x, *a: fn(
-                    sp, x, *a, first=False, last=last))
+                    def call(sp, tokens, *a):
+                        return fn(sp, None, *a, first=True, last=last,
+                                  **{token_kw: tokens})
+                else:
+                    def call(sp, x, *a):
+                        return fn(sp, x, *a, first=False, last=last)
+                call.__name__ = call.__qualname__ = fn.__name__
+                return jax.jit(call)
 
             self._stage_jits[key] = {
                 "prefill": stage(self.stage_prefill, "tokens"),

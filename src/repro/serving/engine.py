@@ -513,12 +513,13 @@ class ServingEngine:
             self._recover_fused(sched, report)
             return              # rolled-back steps rerun next round
         self._round_passes += 1
-        for i, (r, s) in enumerate(zip(dec, steps)):
-            tok = self.sampler(logits[i:i + 1], s)
-            self._emit(_SingleSeq(r), tok, s)
-            sched.next_step[r.rid] = s + 1
-            self._round_decodes += 1
-            report.steps_executed += 1
+        with tracing.span("engine.sample", n=len(dec)):
+            for i, (r, s) in enumerate(zip(dec, steps)):
+                tok = self.sampler(logits[i:i + 1], s)
+                self._emit(_SingleSeq(r), tok, s)
+                sched.next_step[r.rid] = s + 1
+                self._round_decodes += 1
+                report.steps_executed += 1
 
     def _recover_fused(self, sched: RoundScheduler,
                        report: EngineReport) -> None:

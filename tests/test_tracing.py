@@ -1,12 +1,15 @@
 """Flight recorder (repro.core.tracing): ring-buffer semantics, causal
-span nesting, cross-thread routing, byte-identical dumps across identical
-serving runs, the three wire-format exporters, and the trace_report
-critical-path gate."""
+span nesting, the host and modeled clocks, cross-thread routing, the
+profiler annotations, the spans of the served page path, byte-identical
+dumps across identical serving runs on the modeled clock, the wire-format
+exporters, and the trace_report critical-path gate."""
 import dataclasses
+import glob
 import importlib.util
 import json
 import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -39,7 +42,7 @@ def test_ring_overwrites_oldest_and_counts_drops():
 def test_span_nesting_parents_and_modeled_clock():
     prev = telemetry.install(telemetry.Telemetry())
     tele = telemetry.current()
-    t = tracing.Tracer()
+    t = tracing.Tracer(clock=tracing.modeled_ns)
     try:
         with t.span("round"):
             tele.advance(1e-6)
@@ -65,7 +68,7 @@ def test_span_nesting_parents_and_modeled_clock():
 
 
 def test_nonowner_thread_routes_to_streamer_cursor():
-    t = tracing.Tracer()
+    t = tracing.Tracer(clock=tracing.modeled_ns)
 
     def worker():
         t.event("xfer", dur_ns=100, bytes=5)
@@ -120,6 +123,87 @@ def test_install_uninstall_restores_previous():
     assert tracing.current() is None
 
 
+def test_default_tracer_reads_the_host_clock_on_every_thread():
+    t = tracing.Tracer()
+    assert t.clock is tracing.host_ns and not t.modeled
+    lo = time.perf_counter_ns()
+    prev = tracing.install(t)
+    try:
+        with tracing.span("round"):
+            with tracing.span("pass"):
+                time.sleep(0.002)
+            tracing.event("sched.plan")
+
+        def worker():
+            tracing.event("xfer", dur_ns=500, bytes=5)
+            tracing.event("stream.task", dur_ns=50)
+
+        th = threading.Thread(target=worker)
+        th.start()
+        th.join()
+    finally:
+        tracing.uninstall(prev)
+    hi = time.perf_counter_ns()
+    evs = {e["name"]: e for e in t.snapshot()["tracks"]["serve"]["events"]}
+    rnd, pas, plan = evs["round"], evs["pass"], evs["sched.plan"]
+    assert lo <= rnd["ts"] <= pas["ts"]
+    assert pas["dur"] >= 2_000_000                     # the sleep, in ns
+    assert pas["ts"] + pas["dur"] <= plan["ts"]
+    assert plan["ts"] <= rnd["ts"] + rnd["dur"] <= hi
+    # streamer-thread events: host times in order, modeled cost as an arg
+    stream = t.snapshot()["tracks"][tracing.STREAM_TRACK]["events"]
+    assert [e["name"] for e in stream] == ["xfer", "stream.task"]
+    assert rnd["ts"] + rnd["dur"] <= stream[0]["ts"] <= stream[1]["ts"] <= hi
+    assert all(e["ph"] == "I" and "dur" not in e for e in stream)
+    assert [e["args"]["model_ns"] for e in stream] == [500, 50]
+
+
+def _profiled_annotations(tmp_path, body):
+    """The ``repro.`` events a JAX profile of `body` holds, per plane."""
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path = sorted(glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                            recursive=True))[-1]
+    return [(plane.name, ev.name)
+            for plane in ProfileData.from_file(path).planes
+            for line in plane.lines for ev in line.events
+            if ev.name.startswith(tracing.ANNOTATION_PREFIX)]
+
+
+def test_spans_land_on_the_profiler_host_plane(tmp_path):
+    t = tracing.Tracer()
+
+    def body():
+        prev = tracing.install(t)
+        try:
+            with tracing.span("pass"):
+                with tracing.span("worker.gather", wid=0):
+                    time.sleep(0.001)
+        finally:
+            tracing.uninstall(prev)
+
+    found = _profiled_annotations(tmp_path, body)
+    assert ("/host:CPU", "repro.worker.gather") in found
+    assert ("/host:CPU", "repro.pass") in found
+    assert not any(p.startswith("/device:") for p, _ in found)
+
+
+def test_nothing_recorded_or_annotated_without_a_tracer(tmp_path):
+    def body():
+        with tracing.span("worker.gather", wid=0) as args:
+            assert args is None
+        tracing.event("xfer", bytes=1)
+
+    assert tracing.current() is None
+    assert _profiled_annotations(tmp_path, body) == []
+    assert tracing.current() is None
+
+
 # ---------------------------------------------------------------------------
 # engine level: a traced faulted run through the real serving stack
 # ---------------------------------------------------------------------------
@@ -145,7 +229,7 @@ def _traced_run(served):
     from repro.serving import Request, ServingEngine
     cfg, model, params, prompts = served
     prev_tele = telemetry.install(telemetry.Telemetry())
-    tracer = tracing.Tracer()
+    tracer = tracing.Tracer(clock=tracing.modeled_ns)
     prev_tr = tracing.install(tracer)
     try:
         eng = ServingEngine(cfg, model, params, 2, paged=True, tiered=True,
@@ -176,12 +260,96 @@ def test_traced_run_covers_all_sources(traced):
     serve_names = {e["name"] for e in trace["tracks"]["serve"]["events"]}
     assert {"round", "pass", "sched.admit", "sched.plan", "sched.retire",
             "emit.first_token", "cluster.kill", "recovery"} <= serve_names
+    # the host-side layers of every stage op, tagged with their stage
+    assert {"worker.gather", "worker.upload", "worker.stage",
+            "worker.readback", "kvpool.append", "kvpool.write_window",
+            "dejavulib.replicate", "engine.sample"} <= serve_names
+    assert {e["args"]["wid"] for e in trace["tracks"]["serve"]["events"]
+            if e["name"] == "worker.stage"} >= {0, 1}
     stream_names = {e["name"]
                     for e in trace["tracks"]["streamer"]["events"]}
     assert {"xfer", "stream.task", "fault.delay"} <= stream_names
-    # per-worker stage tracks exist alongside serve/streamer
-    assert any(t.startswith("w") for t in trace["tracks"])
+    assert sorted(trace["tracks"]) == ["serve", "streamer"]
     assert all(t["dropped"] == 0 for t in trace["tracks"].values())
+
+
+def _children(events, parent):
+    out = {}
+    for e in events:
+        if e.get("parent") == parent["eid"]:
+            out.setdefault(e["name"], []).append(e)
+    return out
+
+
+def test_page_path_spans_nest_under_each_pass(served):
+    """On a 2-stage model, every fused decode pass and chunk-set pass holds
+    the host page path's spans, once per stage, with byte counts equal to
+    the arrays moved; replication nests under the pass, sampling does not."""
+    from repro.serving import Request, ServingEngine
+    cfg, model, params, _ = served
+    # prompts of three chunks: after each admission's first chunk, the
+    # rest run as chunk-set passes beside the decodes
+    cfg = dataclasses.replace(cfg, prefill_chunk_tokens=8)
+    prompts = np.random.default_rng(1).integers(0, cfg.vocab_size, (3, 20))
+    tracer = tracing.Tracer()
+    prev = tracing.install(tracer)
+    try:
+        eng = ServingEngine(cfg, model, params, 2, paged=True,
+                            kv_pool_blocks=64, replication=True)
+        eng.run_continuous([Request(rid=i, prompt=p.astype(np.int32),
+                                    max_new=4)
+                            for i, p in enumerate(prompts)], max_active=2)
+    finally:
+        tracing.uninstall(prev)
+    snap = tracer.snapshot()
+    evs = snap["tracks"]["serve"]["events"]
+    xfers = snap["tracks"]["streamer"]["events"]
+    item = np.dtype(cfg.dtype).itemsize
+    row = cfg.num_kv_heads * cfg.resolved_head_dim * item   # one token, layer
+    layers = cfg.num_layers // 2
+    per_stage = ("worker.gather", "worker.stage", "worker.readback",
+                 "kvpool.write_window")
+    passes = [e for e in evs if e["name"] == "pass"]
+    kinds = {e["args"]["kind"] for e in passes}
+    assert {"fused_decode", "chunkset"} <= kinds
+    for p in passes:
+        kind = p["args"]["kind"]
+        if kind not in ("fused_decode", "chunkset"):
+            continue
+        kids = _children(evs, p)
+        want = per_stage + (("kvpool.append",) if kind == "fused_decode"
+                            else ())
+        for name in want:
+            assert sorted(e["args"]["wid"] for e in kids[name]) == [0, 1], \
+                (kind, name)
+        n = len(p["args"]["rids"])
+        for g in kids["worker.gather"]:
+            # K and V upload inside the gather, each as soon as it is dense
+            ups = _children(evs, g)["worker.upload"]
+            assert [u["args"]["wid"] for u in ups] == [g["args"]["wid"]] * 2
+            assert g["args"]["n"] == n
+            assert [u["args"]["bytes"] for u in ups] == \
+                [layers * n * g["args"]["pad_to"] * row] * 2
+        if kind == "fused_decode":
+            tb = 8
+            for rb in kids["worker.readback"]:
+                assert rb["args"]["bytes"] == 2 * layers * n * tb * row
+            rep, = kids["dejavulib.replicate"]
+            assert rep["args"]["n"] == n
+            # what the streamer sent while the pass waited for it
+            sent = sum(x["args"]["bytes"] for x in xfers
+                       if x["name"] == "xfer"
+                       and rep["ts"] <= x["ts"] <= rep["ts"] + rep["dur"])
+            assert rep["args"]["bytes"] == sent > 0
+        # children lie inside their pass
+        for es in kids.values():
+            for e in es:
+                assert p["ts"] <= e["ts"]
+                assert e["ts"] + e.get("dur", 0) <= p["ts"] + p["dur"]
+    samples = [e for e in evs if e["name"] == "engine.sample"]
+    assert samples and all(
+        next(q for q in evs if q["eid"] == e["parent"])["name"] == "round"
+        for e in samples)
 
 
 def test_determinism_byte_identical_dumps(served, traced):
@@ -240,23 +408,6 @@ def test_prometheus_export_text_format(traced):
     buckets = [int(line.rsplit(" ", 1)[1]) for line in lines
                if line.startswith("engine_ttft_s_bucket{")]
     assert buckets == sorted(buckets)
-
-
-def test_otlp_export_parents_resolve(traced):
-    _, trace, _, _ = traced
-    doc = exporters.trace_to_otlp(trace)
-    spans = doc["resourceSpans"][0]["scopeSpans"][0]["spans"]
-    assert len(spans) == sum(len(t["events"])
-                             for t in trace["tracks"].values())
-    serve_ids = {s["spanId"] for s in spans
-                 if any(a["key"] == "track"
-                        and a["value"]["stringValue"] == "serve"
-                        for a in s["attributes"])}
-    parents = {s["parentSpanId"] for s in spans if "parentSpanId" in s}
-    assert parents and parents <= serve_ids     # causal links resolve
-    assert all(len(s["traceId"]) == 32 and len(s["spanId"]) == 16
-               for s in spans)
-    json.dumps(doc)
 
 
 # ---------------------------------------------------------------------------
