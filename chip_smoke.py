@@ -157,8 +157,9 @@ def stage_devices(eng):
 
 
 def check_kernel_lowering(cfg, pad: int) -> None:
-    """The served KV writeback (`kv_pack_ragged_auto`, as a fused decode
-    round calls it) must lower to a compiled TPU kernel, not interpret."""
+    """The ragged KV pack of the `kv_pack` family (`kv_pack_ragged_auto`)
+    must lower to a compiled TPU kernel, not interpret.  The paged passes
+    no longer call it: they write their blocks on the device in XLA."""
     import jax
     import jax.numpy as jnp
 

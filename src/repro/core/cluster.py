@@ -325,6 +325,12 @@ class DejaVuCluster:
                 and self.cfg.family in ("dense", "moe")
                 and not self.cfg.num_patches)
 
+    def check_context_fits(self, n_tokens: int) -> None:
+        """Raise ValueError if a context of `n_tokens` text tokens would
+        outgrow any stage's fixed block-table width."""
+        for w in set(self.prompt_group + self.token_group):
+            w.check_table_fits(n_tokens + self.cfg.context_overhead)
+
     def prefill_seq_begin(self, rid: int, prompt: np.ndarray,
                           max_new: int) -> None:
         """Stage a prefill for `prefill_seq_step` to advance pass by pass.
@@ -338,6 +344,7 @@ class DejaVuCluster:
         so the scheduler can interleave decodes between passes."""
         assert self.paged, "prefill_seq requires paged=True"
         plen = int(prompt.shape[0])
+        self.check_context_fits(plen)    # refuse before any state changes
         self.seq_prompt_len[rid] = plen
         self.seq_len[rid] = plen
         token_ids = [int(t) for t in prompt]
@@ -509,7 +516,8 @@ class DejaVuCluster:
     def decode_seq(self, rid: int, token: jnp.ndarray, step: int) -> jnp.ndarray:
         """One decode step for one sequence through the token pipeline.
         Raises PoolExhausted BEFORE mutating any pool, so the engine can
-        preempt a victim and retry."""
+        preempt a victim and retry (ValueError, as early, past the fixed
+        table width)."""
         pos = self.seq_len[rid]
         with telemetry.span("pass", kind="perseq_decode"), \
                 tracing.span("pass", rid=rid, seq=step, kind="perseq_decode"):
@@ -517,6 +525,7 @@ class DejaVuCluster:
                 for w in self.token_group:
                     w.paged_restore(rid)
             for w in self.token_group:
+                w.check_table_fits(w.pool.seq_lens[rid] + 1)
                 if w.pool.append_needs_block(rid) and w.pool.num_free() == 0:
                     raise PoolExhausted(
                         f"worker {w.wid} pool full (seq {rid})")
@@ -545,9 +554,10 @@ class DejaVuCluster:
         (fused rounds) — ragged per-sequence lengths over per-sequence block
         tables, vs `decode_seq`'s one pass per sequence.
 
-        Per-sequence semantics are preserved exactly: capacity is pre-flighted
-        across the WHOLE batch so PoolExhausted raises before any pool
-        mutates (the engine preempts a victim and retries); replication still
+        Per-sequence semantics are preserved exactly: capacity and the fixed
+        table width are pre-flighted across the WHOLE batch so PoolExhausted
+        (the engine preempts a victim and retries) or ValueError (a context
+        past the width) raises before any pool mutates; replication still
         pushes each sequence's touched block with its own step; swap restores
         / offloads every sequence around the pass; and a worker death
         mid-pass surfaces as RuntimeError for the engine's detect-and-recover,
@@ -565,6 +575,8 @@ class DejaVuCluster:
                         for rid in rids:
                             w.paged_restore(rid)
                 for w in self.token_group:
+                    for rid in rids:
+                        w.check_table_fits(w.pool.seq_lens[rid] + 1)
                     need = sum(1 for rid in rids
                                if w.pool.append_needs_block(rid))
                     if need > w.pool.num_free():
@@ -633,38 +645,34 @@ class DejaVuCluster:
 
     def _replicate_seqs(self, items: List[Tuple[int, int, Optional[int]]]
                         ) -> None:
-        """`_replicate_paged` for each (rid, step, pos), as one
-        ``dejavulib.replicate`` span with the bytes pushed."""
-        with tracing.span("dejavulib.replicate", n=len(items)) as sp:
-            pushed = sum(self._replicate_paged(rid, step, pos)
-                         for rid, step, pos in items)
-            if sp is not None:
-                sp["bytes"] = pushed
-
-    def _replicate_paged(self, rid: int, step: int,
-                         pos: Optional[int] = None) -> int:
-        """Ring-replicate at BLOCK granularity: prefill pushes every live
-        block, a decode step pushes only the block it touched.  Waits for
-        the streams; returns the bytes pushed."""
+        """Ring-replicate at BLOCK granularity, as one
+        ``dejavulib.replicate`` span with the bytes pushed: for each (rid,
+        step, pos), a prefill (pos None) pushes every live block, a decode
+        step only the block it touched.  Each stage reads the blocks of
+        every item off its device at once.  Waits for each item's
+        streams."""
         group = self.token_group
         n = len(group)
-        pushed = 0
-        for i, w in enumerate(group):
-            if rid not in w.pool.tables:
-                continue
-            peer = group[(i + 1) % n]
-            if pos is None:
-                for j, arrays in w.live_blocks(rid).items():
-                    pushed += w.cache.replicate_block_to(
-                        peer.cache, rid, j, arrays, step,
-                        self.controller.ack_replication)
-            else:
-                j, arrays = w.touched_block(rid, pos)
-                pushed += w.cache.replicate_block_to(
-                    peer.cache, rid, j, arrays, step,
-                    self.controller.ack_replication)
-        self.streamer.drain()
-        return pushed
+        with tracing.span("dejavulib.replicate", n=len(items)) as sp:
+            reads = []
+            for w in group:
+                live = [(rid, pos) for rid, _, pos in items
+                        if rid in w.pool.tables]
+                reads.append(dict(zip([rid for rid, _ in live],
+                                      w.read_blocks(live))))
+            pushed = 0
+            for rid, step, _ in items:
+                for i, w in enumerate(group):
+                    if rid not in reads[i]:
+                        continue
+                    peer = group[(i + 1) % n]
+                    for j, arrays in reads[i][rid].items():
+                        pushed += w.cache.replicate_block_to(
+                            peer.cache, rid, j, arrays, step,
+                            self.controller.ack_replication)
+                self.streamer.drain()
+            if sp is not None:
+                sp["bytes"] = pushed
 
     def preempt_seq(self, rid: int) -> None:
         """Swap a running sequence fully out (block-granular) to free pool

@@ -64,6 +64,7 @@ class StreamEngine:
         while True:
             task = self._q.get()
             if task is None:
+                self._q.task_done()
                 return
             extra_model = 0.0
             try:
@@ -100,6 +101,7 @@ class StreamEngine:
                                   (task.model_seconds + extra_model) * 1e9)),
                               failed=task.error is not None)
             task.done.set()
+            self._q.task_done()
 
     def submit(self, fn: Callable[[], object], *, model_seconds: float = 0.0,
                tag: str = "") -> _Task:
@@ -134,6 +136,14 @@ class StreamEngine:
         sentinel = self.submit(lambda: None, tag="drain")
         self.wait(sentinel, timeout)
         self._raise_background_errors()
+
+    def quiesce(self, timeout: float = 60.0) -> None:
+        """Wait until every task submitted so far has run.  Unlike `drain`
+        it submits nothing, fires no fault point and raises nothing, so a
+        telemetry snapshot taken after it holds every task's counters."""
+        with self._q.all_tasks_done:
+            self._q.all_tasks_done.wait_for(
+                lambda: not self._q.unfinished_tasks, timeout)
 
     def _raise_background_errors(self) -> None:
         with self._lock:

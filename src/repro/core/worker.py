@@ -11,8 +11,6 @@ primitives only — no ad-hoc copies).
 """
 from __future__ import annotations
 
-import functools
-import math
 import threading
 import time
 from typing import Dict, Optional, Tuple
@@ -28,7 +26,7 @@ from repro.core.dejavulib import (HostLinkTransport, HostMemoryStore,
                                   StreamEngine)
 from repro.core.dejavulib.transport import DEFAULT_HW, HardwareModel
 from repro.kvcache.paged import (BlockPool, PagedKVCache, PoolExhausted,
-                                 blocks_for)
+                                 blocks_for, window_blocks)
 from repro.kvcache.tiers import KVTierManager, TierConfig
 
 
@@ -242,9 +240,8 @@ class StageWorker:
         fns = model.stage_jits(first=first, last=last)
         self._prefill = fns["prefill"]
         self._decode = fns["decode"]
-        self._prefill_chunk = fns["prefill_chunk"]
-        self._decode_batch = fns["decode_batch"]
-        self._prefill_chunk_batch = fns["prefill_chunk_batch"]
+        self._decode_batch_paged = fns["decode_batch_paged"]
+        self._prefill_chunk_batch_paged = fns["prefill_chunk_batch_paged"]
 
     def put(self, tree):
         """Place host or device arrays on this stage's device."""
@@ -291,12 +288,12 @@ class StageWorker:
         telemetry.count("worker.stage_calls", 1, wid=self.wid)
 
     # A stage op's host layers are `tracing` spans tagged with `wid`:
-    # worker.gather / upload / stage / readback, kvpool.append /
-    # write_window (docs/observability.md, "Spans of the served path").
+    # worker.gather / upload / stage, kvpool.append / write_window
+    # (docs/observability.md, "Spans of the served path").
     def _stage(self, fn, *args):
         """The jitted stage call.  Its span times dispatch only: JAX
-        returns before the device finishes, and the host waits for it in
-        the next read-back."""
+        returns before the device finishes, and the host waits for it
+        where it next reads a result (the sampler's logits)."""
         with tracing.span("worker.stage", wid=self.wid):
             return fn(self.sp, *args)
 
@@ -340,12 +337,33 @@ class StageWorker:
     # paged mode: per-sequence KV in ref-counted blocks (see kvcache.paged)
     # ------------------------------------------------------------------
     def enable_paging(self, num_blocks: int, block_size: int) -> None:
+        """A block pool and its pages on this stage's device.  Every paged
+        pass hands the stage program block tables of one fixed width, the
+        blocks of the longest context the model serves, so its shapes vary
+        only with the batch (and the chunk length)."""
         cfg = self.model.cfg
         self.pool = BlockPool(num_blocks, block_size)
         self.pages = PagedKVCache(self.pool, layers=self.hi - self.lo,
                                   num_kv_heads=cfg.num_kv_heads,
                                   head_dim=cfg.resolved_head_dim,
-                                  dtype=cfg.dtype)
+                                  dtype=cfg.dtype, device=self.device)
+        # a sequence never holds more blocks than the pool has, so a model
+        # whose context bound is far past the pool gets the pool's width
+        self.table_width = min(
+            blocks_for(cfg.max_seq_len + cfg.context_overhead, block_size),
+            num_blocks)
+
+    def check_table_fits(self, n_tokens: int) -> None:
+        """Raise ValueError if a context of `n_tokens` would outgrow the
+        fixed table width.  Callers check before any pool change, so a
+        refused context leaves the pool and its pages as they were."""
+        need = blocks_for(n_tokens, self.pool.block_size)
+        if need > self.table_width:
+            raise ValueError(
+                f"worker {self.wid}: a context of {n_tokens} tokens needs "
+                f"{need} blocks, past the table width {self.table_width} "
+                f"(max_seq_len {self.model.cfg.max_seq_len}, "
+                f"{self.pool.num_blocks} blocks in the pool)")
 
     def enable_tiering(self, tier_cfg: TierConfig = TierConfig()) -> None:
         """Back this stage's pool with host-RAM and SSD tiers (see
@@ -367,12 +385,19 @@ class StageWorker:
         self._check()
         x, ks, vs = self._stage(self._prefill, x_or_tokens)
         s = ks.shape[2]
+        self.check_table_fits(s)
         _, fresh = self.pool.allocate(seq, s, token_ids=token_ids)
         # shared blocks already hold identical data (same prefix, same
         # weights); rewriting them is a no-op value-wise, so write the window
         # once instead of per-fresh-block bookkeeping
-        self.pages.write_window(seq, {"k": np.asarray(ks[:, 0]),
-                                      "v": np.asarray(vs[:, 0])}, 0)
+        table, t0, lens = self._tables([seq], [0], [s])
+        # the prompt's slots, block by block (`block_window` from token 0)
+        pad = window_blocks(s, self.pool.block_size) * self.pool.block_size
+        with tracing.span("kvpool.write_window", wid=self.wid, n=1):
+            self.pages.write_window(table, t0, {
+                leaf: jnp.pad(a, ((0, 0), (0, 0), (0, pad - s), (0, 0),
+                                  (0, 0)))
+                for leaf, a in (("k", ks), ("v", vs))}, lens)
         self.paged_dirty[seq] = {j for j, _, _, _ in self.pool.block_span(seq)}
         return x, len(fresh)
 
@@ -383,8 +408,10 @@ class StageWorker:
         fresh blocks are NOT published until their pages are written, see
         `publish_prefix_hashes`); an adopted-prefix table (block-aligned,
         from `adopt_prefix`) is appended out to the full prompt length.
-        Raises PoolExhausted before mutating."""
+        Raises PoolExhausted (or ValueError past the table width) before
+        mutating."""
         self._check()
+        self.check_table_fits(plen)
         if seq not in self.pool.tables:
             self.pool.allocate(seq, plen, token_ids=token_ids, publish=False)
             self.paged_dirty.setdefault(seq, set())
@@ -405,181 +432,97 @@ class StageWorker:
             self.pool.publish_hashes(seq, hashes[:n])
 
     def prefill_chunk_paged(self, seq: int, x_or_tokens, pos0: int):
-        """One chunk [pos0, pos0+C) of a paged prefill: densify the pool
-        pages, run the chunked stage fn (the chunk attends over the resident
-        prefix plus itself — `paged_prefill_attention` semantics), and
-        scatter the chunk's K/V window back into its pages through kv_pack
-        (DMA-aligned; the re-written head tokens of the aligned window hold
-        identical values).  Requires `ensure_prefill_table` first."""
-        self._check()
-        c = int(x_or_tokens.shape[1])
-        kc, vc, pad_to = self._gather_batch([seq])
-        x, kc, vc = self._stage(self._prefill_chunk, x_or_tokens, kc, vc,
-                                jnp.int32(pos0))
-        with tracing.span("worker.readback", wid=self.wid) as sp:
-            win = self._chunk_window(kc, vc, pos0, c, pad_to)
-            if sp is not None:
-                sp["bytes"] = win[0]["k"].nbytes + win[0]["v"].nbytes
-        with tracing.span("kvpool.write_window", wid=self.wid, n=1):
-            self._write_chunk_window(seq, win, pos0, c)
-        return x
-
-    def _chunk_window(self, kc, vc, pos0: int, c: int, pad_to: int):
-        """One chunk's K/V window [pos0, pos0+c), DMA-aligned, off the
-        device through kv_pack (kc/vc: [Lstage, 1, S, H, D]; the re-written
-        head tokens of the aligned window hold identical values) ->
-        (window, aligned start)."""
-        from repro.kernels import ops as kops
-        tb = self.cache.token_block
-        t0a = (pos0 // tb) * tb
-        w = min(-(-(pos0 + c - t0a) // tb) * tb, pad_to - t0a)
-        # a pool whose block size does not divide the DMA token block can
-        # clip the window off-alignment: shrink the copy granularity so the
-        # pack still covers it exactly (t0a stays tb-aligned, so any divisor
-        # of tb is a valid granularity)
-        tbw = tb if w % tb == 0 else math.gcd(w, tb)
-        kc, vc = self.put((kc, vc))          # the pack runs on this stage
-        return ({"k": np.asarray(kops.kv_pack_auto(kc, t0a, w, token_block=tbw))[:, 0],
-                 "v": np.asarray(kops.kv_pack_auto(vc, t0a, w, token_block=tbw))[:, 0]},
-                t0a)
-
-    def _write_chunk_window(self, seq: int, win, pos0: int, c: int) -> None:
-        """Scatter a `_chunk_window` back into `seq`'s pages.  Shared by the
-        per-sequence and fused chunk paths so alignment and dirty-block
-        accounting can never drift between them."""
-        arrays, t0a = win
-        bs = self.pool.block_size
-        self.pages.write_window(seq, arrays, t0a)
-        self.paged_dirty.setdefault(seq, set()).update(
-            range(t0a // bs, -(-(pos0 + c) // bs)))
+        """One chunk [pos0, pos0+C) of a paged prefill: the chunk-set pass
+        with one sequence.  Requires `ensure_prefill_table` first."""
+        return self.prefill_chunk_paged_batch([seq], x_or_tokens, [pos0],
+                                              [int(x_or_tokens.shape[1])])
 
     def decode_paged(self, seq: int, x_or_token, pos: int):
-        """One decode step for one sequence: append a slot (CoW if the tail
-        block is shared), gather blocks -> dense stage cache, run the jitted
-        stage, scatter the new token's K/V back into its block."""
-        self._check()
-        self._append_slots([seq])
-        kc, vc, _ = self._gather_batch([seq])
-        x, kc, vc = self._stage(self._decode, x_or_token, kc, vc,
-                                jnp.int32(pos))
-        with tracing.span("worker.readback", wid=self.wid) as sp:
-            win = {"k": np.asarray(kc[:, 0, pos:pos + 1]),
-                   "v": np.asarray(vc[:, 0, pos:pos + 1])}
-            if sp is not None:
-                sp["bytes"] = win["k"].nbytes + win["v"].nbytes
-        with tracing.span("kvpool.write_window", wid=self.wid, n=1):
-            self.pages.write_window(seq, win, pos)
-            self.paged_dirty.setdefault(seq, set()).add(
-                pos // self.pool.block_size)
-        return x
+        """One decode step for one sequence: the fused decode pass with one
+        sequence."""
+        return self.decode_paged_batch([seq], x_or_token, [pos])
 
     def _append_slots(self, seqs) -> None:
         """One new slot per sequence (CoW where its tail block is shared)."""
+        for seq in seqs:
+            self.check_table_fits(self.pool.seq_lens[seq] + 1)
         with tracing.span("kvpool.append", wid=self.wid, n=len(seqs)):
-            for seq in seqs:
-                cow = self.pool.append(seq)
-                self.pages.apply_cow(cow)
+            cow = [c for seq in seqs for c in self.pool.append(seq)]
+            self.pages.apply_cow(cow)
 
-    def _gather_batch(self, seqs) -> Tuple[jax.Array, jax.Array, int]:
-        """Densify every sequence's pages to a common pad (ragged lengths
-        over per-sequence block tables) and upload -> (kc, vc, pad_to) with
-        kc/vc [Lstage, B, pad_to, H, D] — the fused-round stage-cache
-        layout."""
-        pad_to = max(len(self.pool.tables[s]) for s in seqs) * self.pool.block_size
-        out = []
+    def _tables(self, seqs, *per_seq):
+        """The pass's block tables ``[B, table_width]`` and its per-sequence
+        int32 vectors (positions, chunk lengths), placed on this stage's
+        device: the only bytes a paged pass uploads."""
         with tracing.span("worker.gather", wid=self.wid, n=len(seqs),
-                          pad_to=pad_to):
-            dense = [self.pages.gather_dense(s, pad_to) for s in seqs]
-            for leaf in ("k", "v"):
-                a = (dense[0][leaf] if len(dense) == 1 else
-                     np.concatenate([d[leaf] for d in dense], axis=1))
-                # uploaded before V is concatenated: device_put returns
-                # before its DMA, which overlaps that host work
-                with tracing.span("worker.upload", wid=self.wid) as sp:
-                    out.append(self.put(a))
-                    if sp is not None:
-                        sp["bytes"] = a.nbytes
-        kc, vc = out
-        return kc, vc, pad_to
+                          width=self.table_width):
+            arrays = (self.pool.padded_tables(seqs, self.table_width),
+                      *(np.asarray(v, np.int32) for v in per_seq))
+            with tracing.span("worker.upload", wid=self.wid) as sp:
+                out = self.put(arrays)
+                if sp is not None:
+                    sp["bytes"] = sum(a.nbytes for a in arrays)
+        return out
+
+    def _write_back(self, seqs, table, pos, k_slots, v_slots, lens, dirty):
+        """The blocks the pass wrote into, back into the pages on the
+        device; `dirty[i]` lists the logical blocks sequence i wrote."""
+        with tracing.span("kvpool.write_window", wid=self.wid, n=len(seqs)):
+            self.pages.write_window(table, pos, {"k": k_slots, "v": v_slots},
+                                    lens)
+            for seq, js in zip(seqs, dirty):
+                self.paged_dirty.setdefault(seq, set()).update(js)
 
     def decode_paged_batch(self, seqs, x_or_tokens, poses):
         """ONE fused pipeline pass: every sequence in `seqs` decodes one step
         at its OWN position.  Appends a slot per sequence (CoW where shared),
-        gathers the ragged block tables into a common-padded batch cache,
-        runs the batched stage fn, and scatters each sequence's new-token K/V
-        window back through one multi-sequence ragged buffered copy.  The
-        cluster pre-flights pool capacity for the WHOLE batch first, so the
-        per-sequence appends here cannot run out mid-batch."""
+        runs the paged stage program over the block tables (it gathers each
+        layer's cache from the pages on the device), and writes each
+        sequence's new token back into its block.  The cluster pre-flights
+        pool capacity for the WHOLE batch first, so the per-sequence appends
+        here cannot run out mid-batch."""
         self._check()
-        from repro.kernels import ops as kops
         bs = self.pool.block_size
         self._append_slots(seqs)
-        kc, vc, pad_to = self._gather_batch(seqs)
-        x, kc, vc = self._stage(self._decode_batch, x_or_tokens, kc, vc,
-                                self.put(np.asarray(poses, np.int32)))
-        tb = self.cache.token_block
-        t0s = [(p // tb) * tb for p in poses]
-        with tracing.span("worker.readback", wid=self.wid) as sp:
-            if pad_to % tb == 0:
-                # one ragged pack gathers every sequence's aligned one-token
-                # window (vs B separate kv_pack launches); the aligned head
-                # tokens re-write identical values, like the per-seq chunk
-                # path
-                starts = np.asarray(t0s, np.int32)
-                k = np.asarray(kops.kv_pack_ragged_auto(kc, starts, tb,
-                                                        token_block=tb))
-                v = np.asarray(kops.kv_pack_ragged_auto(vc, starts, tb,
-                                                        token_block=tb))
-                wins = [({"k": k[:, i], "v": v[:, i]}, t0s[i])
-                        for i in range(len(seqs))]
-            else:                        # unaligned pool blocks: plain slices
-                k, v = np.asarray(kc), np.asarray(vc)
-                wins = [({"k": k[:, i, p:p + 1], "v": v[:, i, p:p + 1]}, p)
-                        for i, p in enumerate(poses)]
-            if sp is not None:
-                sp["bytes"] = k.nbytes + v.nbytes
-        with tracing.span("kvpool.write_window", wid=self.wid, n=len(seqs)):
-            for i, seq in enumerate(seqs):
-                win, t0 = wins[i]
-                self.pages.write_window(seq, win, t0)
-                self.paged_dirty.setdefault(seq, set()).add(poses[i] // bs)
+        table, pos = self._tables(seqs, poses)
+        x, k, v = self._stage(self._decode_batch_paged, x_or_tokens,
+                              self.pages.k, self.pages.v, table, pos)
+        self._write_back(seqs, table, pos, k, v, None,
+                         [[p // bs] for p in poses])
         return x
 
     def prefill_chunk_paged_batch(self, seqs, x_or_tokens, pos0s, q_lens):
         """One fused chunk-set pass: one prefill chunk of EACH sequence runs
-        in a single pipeline pass (`stage_prefill_chunk_batch`), sequence i's
-        chunk holding ``q_lens[i]`` valid tokens at positions ``pos0s[i]..``
-        and attending over its own resident prefix plus itself.  Each
-        sequence's K/V window scatters back into its own pages.  Requires
-        `ensure_prefill_table` for every sequence first."""
+        in a single pipeline pass (`stage_prefill_chunk_batch_paged`),
+        sequence i's chunk holding ``q_lens[i]`` valid tokens at positions
+        ``pos0s[i]..`` and attending over its own resident prefix plus
+        itself.  Each sequence's valid rows are written into its own pages;
+        padding rows touch no block.  Requires `ensure_prefill_table` for
+        every sequence first."""
         self._check()
-        kc, vc, pad_to = self._gather_batch(seqs)
-        x, kc, vc = self._stage(self._prefill_chunk_batch, x_or_tokens, kc,
-                                vc, self.put(np.asarray(pos0s, np.int32)),
-                                self.put(np.asarray(q_lens, np.int32)))
-        with tracing.span("worker.readback", wid=self.wid) as sp:
-            kc_np, vc_np = np.asarray(kc), np.asarray(vc)
-            wins = [self._chunk_window(kc_np[:, i:i + 1], vc_np[:, i:i + 1],
-                                       pos0s[i], q_lens[i], pad_to)
-                    for i in range(len(seqs))]
-            if sp is not None:
-                sp["bytes"] = kc_np.nbytes + vc_np.nbytes + sum(
-                    w["k"].nbytes + w["v"].nbytes for w, _ in wins)
-        with tracing.span("kvpool.write_window", wid=self.wid, n=len(seqs)):
-            for i, seq in enumerate(seqs):
-                self._write_chunk_window(seq, wins[i], pos0s[i], q_lens[i])
+        bs = self.pool.block_size
+        table, pos, lens = self._tables(seqs, pos0s, q_lens)
+        x, k, v = self._stage(self._prefill_chunk_batch_paged, x_or_tokens,
+                              self.pages.k, self.pages.v, table, pos, lens)
+        self._write_back(seqs, table, pos, k, v, lens,
+                         [range(p // bs, -(-(p + c) // bs))
+                          for p, c in zip(pos0s, q_lens)])
         return x
 
-    def touched_block(self, seq: int, pos: int):
-        """(logical_idx, arrays) of the block holding token `pos`."""
-        j = pos // self.pool.block_size
-        _, bid, t0, t1 = next(sp for sp in self.pool.block_span(seq)
-                              if sp[0] == j)
-        return j, self.pages.block_arrays(bid, width=t1 - t0)
+    def read_blocks(self, want) -> list:
+        """Blocks of several sequences off the device in one read: `want`
+        is ``[(seq, pos | None), ...]`` — the block holding token `pos`, or
+        every live block.  Returns, per item, ``{logical_idx: arrays}``."""
+        spans = [[(j, bid, t1 - t0)
+                  for j, bid, t0, t1 in self.pool.block_span(seq)
+                  if pos is None or j == pos // self.pool.block_size]
+                 for seq, pos in want]
+        flat = iter(self.pages.read_blocks([(bid, w) for items in spans
+                                            for _, bid, w in items]))
+        return [{j: next(flat) for j, _, _ in items} for items in spans]
 
     def live_blocks(self, seq: int) -> Dict[int, Dict[str, np.ndarray]]:
-        return {j: self.pages.block_arrays(bid, width=t1 - t0)
-                for j, bid, t0, t1 in self.pool.block_span(seq)}
+        return self.read_blocks([(seq, None)])[0]
 
     def install_blocks(self, seq: int, length: int,
                        blocks: Dict[int, Dict[str, np.ndarray]],
@@ -593,9 +536,9 @@ class StageWorker:
             self.pool.free_seq(seq)
         table, fresh = self.pool.allocate(seq, length, hashes=hashes)
         fresh_set = set(fresh)
-        for j, bid in enumerate(table):
-            if j in blocks and j in fresh_set:
-                self.pages.install_block(bid, blocks[j])
+        self.pages.install_blocks([(bid, blocks[j])
+                                   for j, bid in enumerate(table)
+                                   if j in blocks and j in fresh_set])
         # shared blocks hold live data too: they must survive an offload
         self.paged_dirty[seq] = set(blocks) | (set(range(len(table)))
                                                - fresh_set)
@@ -653,10 +596,14 @@ class StageWorker:
         self.cache.drop_seq_swap(seq)
 
     def _demote_prefix_blocks(self, seq: int) -> None:
-        for j, bid, t0, t1 in self.pool.block_span(seq):
-            h = self.pool.blocks[bid].hash
-            if h is not None and not self.tier.has_prefix(h):
-                self.tier.cache_prefix_block(h, self.pages.block_arrays(bid))
+        bs = self.pool.block_size
+        hashed = [(self.pool.blocks[bid].hash, bid)
+                  for _, bid, _, _ in self.pool.block_span(seq)]
+        hashed = [(h, bid) for h, bid in hashed
+                  if h is not None and not self.tier.has_prefix(h)]
+        arrays = self.pages.read_blocks([(bid, bs) for _, bid in hashed])
+        for (h, _), arr in zip(hashed, arrays):
+            self.tier.cache_prefix_block(h, arr)
 
     # --- cross-request prefix reuse ------------------------------------
     def adoptable_prefix_len(self, hashes) -> int:
@@ -694,8 +641,7 @@ class StageWorker:
         fetched = (self.tier.fetch_prefix_chain(missing)
                    if missing and self.tier is not None else {})
         _, fills = self.pool.adopt_prefix(seq, hashes, length)
-        for h, bid in fills:
-            self.pages.install_block(bid, fetched[h])
+        self.pages.install_blocks([(bid, fetched[h]) for h, bid in fills])
         # adopted blocks count as dirty: the first offload must persist them
         # for this sequence (tier copies are keyed by hash, not by seq)
         self.paged_dirty[seq] = {j for j in range(len(hashes))}

@@ -8,10 +8,11 @@ contiguous per-microbatch cache sized ``prompt + max_new``:
                      tables, alloc/append/free, prefix-sharing (hash-chain
                      over full prompt blocks, copy-on-write on divergence),
                      and defragmentation (compaction to the lowest ids);
-  ``PagedKVCache``   data plane — the actual page arrays for one pipeline
-                     stage ``[num_blocks, Lstage, block_size, Hkv, Dh]`` plus
-                     gather (blocks -> dense cache for the decode kernel) and
-                     scatter (dense window -> blocks) helpers.
+  ``PagedKVCache``   data plane — the page arrays of one pipeline stage
+                     ``[Lstage, num_blocks, block_size, Hkv * Dh]`` on its
+                     device, with the jitted writers and the one reader;
+                     the stage programs gather pages by block table
+                     (`gather_layer`) on the device.
 
 Blocks are also DéjàVu's streaming unit: swapping, ring replication, and
 recovery (see `repro.core.worker` / `repro.core.cluster`) move individual
@@ -27,10 +28,15 @@ blocks streamed back out of ANY tier instead of re-prefilling them.
 """
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+
+from repro.core import telemetry
 
 
 class PoolExhausted(MemoryError):
@@ -298,6 +304,19 @@ class BlockPool:
                 return
             yield j, bid, t0, t1
 
+    def padded_tables(self, seqs: Sequence[int], width: int) -> np.ndarray:
+        """The block tables of `seqs` as one int32 ``[B, width]`` array,
+        each padded with block 0.  Pad slots lie past every sequence's live
+        length, where the attention mask hides them."""
+        out = np.zeros((len(seqs), width), np.int32)
+        for i, seq in enumerate(seqs):
+            table = self.tables[seq]
+            if len(table) > width:
+                raise ValueError(f"seq {seq} holds {len(table)} blocks, past "
+                                 f"the table width {width}")
+            out[i, :len(table)] = table
+        return out
+
     # --- defragmentation ------------------------------------------------
     def defrag(self) -> Dict[int, int]:
         """Compact live blocks onto the lowest ids (so a pool shrink / a
@@ -323,28 +342,135 @@ class BlockPool:
         return moves
 
 
+# ---------------------------------------------------------------------------
+# device data plane: pure functions over one stage's page array
+# ``[Lstage, N, bs, Hkv * Dh]``.  Each (layer, block) is one ``[bs, Hkv*Dh]``
+# slab of whole TPU tiles, so the array is stored all but unpadded (a 5-D
+# ``[..., Hkv, Dh]`` layout pads its two minor dims to whole tiles: 2.56x
+# at 25 heads of 64), and every gather, read and write moves whole slabs:
+# the device never relays out the pool.  Writes therefore go a block at a
+# time, the blocks a pass wrote into, whole.
+# ---------------------------------------------------------------------------
+
+def gather_layer(pages: jax.Array, table: jax.Array, layer, heads: int,
+                 head_dim: int) -> jax.Array:
+    """One layer's dense cache ``[B, T*bs, Hkv, Dh]`` from the pages by
+    block table ``[B, T]``: slot s of sequence b is token s of its
+    context."""
+    slabs = pages[layer, table]                        # [B, T, bs, H*D]
+    b, t, bs, _ = slabs.shape
+    return slabs.reshape(b, t * bs, heads, head_dim)
+
+
+def window_blocks(width: int, block_size: int) -> int:
+    """Blocks a window of `width` tokens can touch, from any start."""
+    return (width + block_size - 2) // block_size + 1
+
+
+def block_window(cache: jax.Array, t0: jax.Array, width: int,
+                 block_size: int) -> jax.Array:
+    """The slots of the blocks that a pass writing tokens ``t0[b] ..
+    t0[b]+width-1`` touches, from the block holding ``t0[b]`` on, out of a
+    dense cache ``[B, S, H, D]`` -> ``[B, window_blocks(width) * bs, H, D]``
+    (slots past S are clipped: their blocks lie past the table)."""
+    n = window_blocks(width, block_size) * block_size
+    idx = (t0 // block_size * block_size)[:, None] \
+        + jnp.arange(n, dtype=jnp.int32)[None, :]
+    idx = jnp.clip(idx, 0, cache.shape[1] - 1)
+    return jnp.take_along_axis(cache, idx[:, :, None, None], axis=1)
+
+
+def scatter_blocks(pages: jax.Array, table: jax.Array, t0: jax.Array,
+                   lens: jax.Array, slots: jax.Array) -> jax.Array:
+    """Write back the blocks each sequence's pass wrote into, whole:
+    ``slots`` ``[Lstage, B, nb*bs, H, D]`` are sequence b's slots from the
+    block holding token ``t0[b]`` on (`block_window`); the blocks holding
+    tokens ``t0[b] .. t0[b]+lens[b]-1`` are written, every layer at once,
+    and the others get an out-of-range id and are dropped."""
+    layers, n, bs, hd = pages.shape
+    _, b, w = slots.shape[:3]
+    nb = w // bs
+    first = t0 // bs
+    j = first[:, None] + jnp.arange(nb, dtype=jnp.int32)[None, :]  # [B, nb]
+    last = (t0 + lens - 1) // bs
+    ok = (j <= last[:, None]) & (lens[:, None] > 0)
+    blk = jnp.where(ok, jnp.take_along_axis(
+        table, jnp.clip(j, 0, table.shape[1] - 1), axis=1), n)
+    upd = slots.reshape(layers, b * nb, bs, hd).astype(pages.dtype)
+    return pages.at[:, blk.reshape(-1)].set(upd, mode="drop")
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _write_window(kp, vp, table, t0, lens, k_slots, v_slots):
+    return (scatter_blocks(kp, table, t0, lens, k_slots),
+            scatter_blocks(vp, table, t0, lens, v_slots))
+
+
+def _at_blocks(pages: jax.Array, bids: jax.Array):
+    """Index of blocks `bids` in every layer of ``[Lstage, N, ...]``, as
+    (layer, block) pairs: indexing the block axis alone makes XLA on TPU
+    copy a third of the pool into a temporary before the gather."""
+    return jnp.arange(pages.shape[0])[:, None], bids[None, :]
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _copy_blocks(kp, vp, src, dst):
+    # every source is read before any destination is written
+    return tuple(p.at[_at_blocks(p, dst)].set(p[_at_blocks(p, src)],
+                                             mode="drop")
+                 for p in (kp, vp))
+
+
+@jax.jit
+def _read_blocks(kp, vp, bids):
+    return jnp.stack([p[_at_blocks(p, bids)] for p in (kp, vp)])
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def _install_blocks(kp, vp, bids, widths, kv):
+    # each block takes kv's first `widths` slots and keeps the rest
+    keep = jnp.arange(kp.shape[2], dtype=jnp.int32)[None, :] \
+        < widths[:, None]
+    return tuple(
+        p.at[_at_blocks(p, bids)].set(
+            jnp.where(keep[None, :, :, None], kv[i].astype(p.dtype),
+                      p[_at_blocks(p, bids)]), mode="drop")
+        for i, p in enumerate((kp, vp)))
+
+
+def _bucket(n: int) -> int:
+    """The power of two at or above `n`: block-list programs compile once
+    per bucket, not once per list length."""
+    return 1 << max(n - 1, 0).bit_length()
+
+
 @dataclass
 class PagedKVCache:
-    """Data plane for one pipeline stage: pages ``[N, Lstage, bs, Hkv, Dh]``.
+    """Data plane for one pipeline stage: its pages as two ``jax.Array``s
+    (K, V) ``[Lstage, N, bs, Hkv * Dh]`` on the stage's `device`.
 
-    Pages are host-of-truth numpy on every backend, the TPU included: each
-    pass gathers a sequence's pages into a dense cache that the stage
-    uploads to its device, and writes the new window back here.  Keeping
-    pages on the device behind `paged_decode_attention` needs that kernel
-    in a head-major K/V layout first (it does not lower on TPU as is)."""
+    The stage programs gather a pass's dense cache from the pages by block
+    table on the device (`gather_layer`); every write — a pass's new window,
+    copy-on-write, defrag, installed blocks — is one jitted update that
+    donates the pages, so they change in place.  Pages leave the device only
+    through `read_blocks`, counted as ``kvpool.block_reads``; blocks cross
+    the host boundary as ``[Lstage, width, Hkv, Dh]`` arrays."""
     pool: BlockPool
     layers: int
     num_kv_heads: int
     head_dim: int
     dtype: str = "float32"
-    k: np.ndarray = field(init=False)
-    v: np.ndarray = field(init=False)
+    device: Optional[jax.Device] = None
+    k: jax.Array = field(init=False)
+    v: jax.Array = field(init=False)
 
     def __post_init__(self):
-        shape = (self.pool.num_blocks, self.layers, self.pool.block_size,
-                 self.num_kv_heads, self.head_dim)
-        self.k = np.zeros(shape, np.dtype(self.dtype))
-        self.v = np.zeros(shape, np.dtype(self.dtype))
+        if self.device is None:
+            self.device = jax.local_devices()[0]
+        shape = (self.layers, self.pool.num_blocks, self.pool.block_size,
+                 self.num_kv_heads * self.head_dim)
+        self.k = jnp.zeros(shape, jnp.dtype(self.dtype), device=self.device)
+        self.v = jnp.zeros(shape, jnp.dtype(self.dtype), device=self.device)
 
     @property
     def block_bytes(self) -> int:
@@ -354,57 +480,78 @@ class PagedKVCache:
     def used_bytes(self) -> int:
         return self.pool.num_used() * self.block_bytes
 
-    # --- dense <-> paged ------------------------------------------------
-    def write_window(self, seq: int, kv: Dict[str, np.ndarray], t0: int) -> List[int]:
-        """Scatter a dense window ``[Lstage, W, H, D]`` (tokens t0..t0+W) of
-        `seq` into its pages.  Returns the bids touched (the streaming
-        delta)."""
-        touched = []
-        for leaf, win in kv.items():
-            pages = self.k if leaf == "k" else self.v
-            w = win.shape[1]
-            for j, bid, b0, b1 in self.pool.block_span(seq):
-                lo, hi = max(b0, t0), min(b1, t0 + w)
-                if lo >= hi:
-                    continue
-                pages[bid, :, lo - b0:hi - b0] = win[:, lo - t0:hi - t0]
-                if leaf == "k":
-                    touched.append(bid)
-        return touched
+    def _ids(self, bids: Sequence[int], pad: int) -> jax.Array:
+        out = np.full(_bucket(len(bids)), pad, np.int32)
+        out[:len(bids)] = bids
+        return jax.device_put(out, self.device)
 
-    def gather_dense(self, seq: int, pad_to: int) -> Dict[str, np.ndarray]:
-        """Assemble `seq`'s live tokens into a dense ``[Lstage, 1, pad_to,
-        H, D]`` cache (the layout `stage_decode` consumes)."""
-        out = {}
-        for leaf, pages in (("k", self.k), ("v", self.v)):
-            dense = np.zeros((self.layers, 1, pad_to, self.num_kv_heads,
-                              self.head_dim), pages.dtype)
-            for j, bid, t0, t1 in self.pool.block_span(seq):
-                dense[:, 0, t0:t1] = pages[bid, :, :t1 - t0]
-            out[leaf] = dense
-        return out
-
-    def copy_block(self, src_bid: int, dst_bid: int) -> None:
-        """Apply a copy-on-write / defrag move to the pages."""
-        self.k[dst_bid] = self.k[src_bid]
-        self.v[dst_bid] = self.v[src_bid]
+    # --- writers --------------------------------------------------------
+    def write_window(self, table, t0, kv: Dict[str, jax.Array],
+                     lens=None) -> None:
+        """Write a pass's window back into the pages: sequence b wrote
+        tokens ``t0[b] .. t0[b]+lens[b]-1`` (``lens`` defaults to 1) into
+        the blocks of row b of the block table ``[B, T]``, and ``kv["k"]`` /
+        ``kv["v"]`` ``[Lstage, B, nb*bs, H, D]`` hold the slots of its
+        blocks from the one holding ``t0[b]`` on (`block_window`).  Those
+        blocks are written whole; a block past ``lens[b]`` is not touched."""
+        t0 = jnp.asarray(t0, jnp.int32)
+        if lens is None:
+            lens = jnp.ones(t0.shape, jnp.int32)
+        self.k, self.v = _write_window(self.k, self.v, table, t0,
+                                       jnp.asarray(lens, jnp.int32),
+                                       kv["k"], kv["v"])
 
     def apply_cow(self, cow: Sequence[Tuple[int, int]]) -> None:
-        for old, new in cow:
-            self.copy_block(old, new)
+        """Copy-on-write directives ``[(old, new), ...]`` from the pool."""
+        if cow:
+            self.k, self.v = _copy_blocks(
+                self.k, self.v, self._ids([o for o, _ in cow], 0),
+                self._ids([n for _, n in cow], self.pool.num_blocks))
 
     def apply_defrag(self, moves: Dict[int, int]) -> None:
-        for old, new in sorted(moves.items(), key=lambda kv: kv[1]):
-            self.copy_block(old, new)
+        """The pool's defrag moves ``{old: new}``: each new id is either
+        free or a block that moves too, so reading every source before
+        writing gives the pool's order of moves."""
+        self.apply_cow(sorted(moves.items(), key=lambda kv: kv[1]))
 
-    def block_arrays(self, bid: int, width: Optional[int] = None
-                     ) -> Dict[str, np.ndarray]:
-        """One block's pages (optionally only the first `width` token slots)
-        — the unit DéjàVuLib streams for swap / replication / recovery."""
-        w = self.pool.block_size if width is None else width
-        return {"k": self.k[bid, :, :w].copy(), "v": self.v[bid, :, :w].copy()}
+    def install_blocks(self, blocks: Sequence[Tuple[int, Dict[str, np.ndarray]]]
+                       ) -> None:
+        """Write host blocks ``[(bid, {"k": [Lstage, w, H, D], "v": ...})]``
+        into their pages (each block's first w token slots): one upload and
+        one update."""
+        if not blocks:
+            return
+        n, bs = _bucket(len(blocks)), self.pool.block_size
+        kv = np.zeros((2, self.layers, n, bs, self.num_kv_heads,
+                       self.head_dim), np.dtype(self.dtype))
+        widths = np.zeros(n, np.int32)
+        for i, (_, arrays) in enumerate(blocks):
+            for li, leaf in enumerate(("k", "v")):
+                arr = np.asarray(arrays[leaf])
+                kv[li, :, i, :arr.shape[1]] = arr
+            widths[i] = min(arrays["k"].shape[1], bs)
+        self.k, self.v = _install_blocks(
+            self.k, self.v, self._ids([b for b, _ in blocks],
+                                      self.pool.num_blocks),
+            jax.device_put(widths, self.device),
+            jax.device_put(kv.reshape(kv.shape[:4] + (-1,)), self.device))
 
-    def install_block(self, bid: int, arrays: Dict[str, np.ndarray]) -> None:
-        for leaf, arr in arrays.items():
-            pages = self.k if leaf == "k" else self.v
-            pages[bid, :, :arr.shape[1]] = arr
+    # --- readers --------------------------------------------------------
+    def read_blocks(self, spans: Sequence[Tuple[int, int]]
+                    ) -> List[Dict[str, np.ndarray]]:
+        """Blocks off the device, as ``[(bid, width), ...]`` -> each block's
+        first `width` token slots ``{"k": [Lstage, width, H, D], "v": ...}``
+        on the host: one take over the block ids and one transfer.  The
+        unit DéjàVuLib streams for swap / replication / recovery / tiers."""
+        if not spans:
+            return []
+        both = np.asarray(_read_blocks(self.k, self.v,
+                                       self._ids([b for b, _ in spans], 0)))
+        both = both.reshape(both.shape[:4] + (self.num_kv_heads,
+                                              self.head_dim))
+        telemetry.count("kvpool.block_reads", len(spans))
+        telemetry.count("kvpool.block_read_bytes",
+                        sum(w for _, w in spans) * self.block_bytes
+                        // self.pool.block_size)
+        return [{"k": both[0, :, i, :w], "v": both[1, :, i, :w]}
+                for i, (_, w) in enumerate(spans)]

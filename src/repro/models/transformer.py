@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
+from repro.kvcache.paged import block_window, gather_layer
 from repro.models import attention as attn
 from repro.models.common import (alibi_slopes, embed_init, logical_constraint,
                                  norm_apply, norm_init, split_keys)
@@ -220,7 +221,8 @@ class DecoderLM:
         (a stage on another device reuses the trace and compiles only for
         that device).  Stage 0 takes tokens where the others take
         activations.  Each program is named after its stage function
-        (``jit_stage_decode_batch``), so a device trace tells them apart."""
+        (``jit_stage_decode_batch_paged``), so a device trace tells them
+        apart."""
         key = (first, last)
         if key not in self._stage_jits:
             def stage(fn, token_kw):
@@ -237,10 +239,10 @@ class DecoderLM:
             self._stage_jits[key] = {
                 "prefill": stage(self.stage_prefill, "tokens"),
                 "decode": stage(self.stage_decode, "token"),
-                "prefill_chunk": stage(self.stage_prefill_chunk, "tokens"),
-                "decode_batch": stage(self.stage_decode_batch, "token"),
-                "prefill_chunk_batch": stage(self.stage_prefill_chunk_batch,
-                                             "tokens"),
+                "decode_batch_paged": stage(self.stage_decode_batch_paged,
+                                            "token"),
+                "prefill_chunk_batch_paged": stage(
+                    self.stage_prefill_chunk_batch_paged, "tokens"),
             }
         return self._stage_jits[key]
 
@@ -266,79 +268,69 @@ class DecoderLM:
             x = self._unembed(sp, x[:, -1:, :])[:, 0]
         return x, ks, vs
 
-    def stage_prefill_chunk(self, sp, x, kc, vc, pos, *, first: bool,
-                            last: bool, tokens=None):
-        """Chunked paged prefill for one stage: a chunk of C prompt tokens at
-        absolute positions pos..pos+C-1 attends causally over the cache
-        prefix [0,pos) (densified pool pages) plus itself, writing its K/V
-        into the cache window at `pos`.  Stage 0 passes `tokens` [B,C]; the
-        last stage returns the chunk's final-token logits (only the final
-        chunk's matter — they are the prefill logits).  kc/vc: [Lstage,B,S,H,D].
-        """
-        cfg = self.cfg
-        if first:
-            x = jnp.take(sp["embed"], tokens, axis=0)
-            if cfg.pos_emb == "learned":
-                x = x + jax.lax.dynamic_slice_in_dim(
-                    sp["pos_table"], pos, tokens.shape[1], axis=0)[None]
+    def _paged_layers(self, sp, x, kp, vp, table, pos, q_lens, kv_positions):
+        """The stage's layer scan over pool pages ``[Lstage, N, bs, H*D]``:
+        each layer gathers its dense ``[B, T*bs, H, D]`` cache by block table
+        ``[B, T]`` (`gather_layer`), runs the fused-round attention on it,
+        and keeps only the blocks the pass wrote into, from the one holding
+        ``pos[b]`` on (`block_window`).  Returns (x, k_slots, v_slots),
+        slots ``[Lstage, B, nb*bs, H, D]`` for `PagedKVCache.write_window`."""
         c = x.shape[1]
-        s_cache = kc.shape[2]
-        kv_positions = jnp.arange(s_cache, dtype=jnp.int32)
-        kv_positions = jnp.where(kv_positions < pos + c, kv_positions, -1)
+        hkv, dh = self.cfg.num_kv_heads, self.cfg.resolved_head_dim
+        bs = kp.shape[2]
 
         def body(x, xs):
-            lp, k1, v1, w = xs
-            x, (k1, v1), _ = self._layer(x, lp, mode="decode", kc=k1, vc=v1,
-                                         kv_positions=kv_positions, pos=pos,
-                                         window=w)
-            return x, (k1, v1)
+            lp, layer, w = xs
+            k1 = gather_layer(kp, table, layer, hkv, dh)
+            v1 = gather_layer(vp, table, layer, hkv, dh)
+            x, (k1, v1), _ = self._layer(x, lp, mode="decode_batch", kc=k1,
+                                         vc=v1, kv_positions=kv_positions,
+                                         pos=pos, q_lens=q_lens, window=w)
+            return x, (block_window(k1, pos, c, bs),
+                       block_window(v1, pos, c, bs))
 
-        x, (kc, vc) = jax.lax.scan(body, x, (sp["layers"], kc, vc,
-                                             sp["layer_window"]))
-        if last:
-            x = norm_apply(cfg.norm, x, sp["final_norm"])
-            x = self._unembed(sp, x[:, -1:, :])[:, 0]
-        return x, kc, vc
+        layers = jnp.arange(kp.shape[0], dtype=jnp.int32)
+        x, (k_slots, v_slots) = jax.lax.scan(body, x, (sp["layers"], layers,
+                                                       sp["layer_window"]))
+        return x, k_slots, v_slots
 
-    def stage_decode_batch(self, sp, x, kc, vc, pos, *, first: bool,
-                           last: bool, token=None):
+    def stage_decode_batch_paged(self, sp, x, kp, vp, table, pos, *,
+                                 first: bool, last: bool, token=None):
         """Fused-round decode for one stage: B sequences each advance ONE
         step in a single pipeline pass, sequence b's new token sitting at its
-        OWN position ``pos[b]`` (ragged lengths — vs `stage_decode`'s shared
-        scalar).  kc/vc: [Lstage,B,S,H,D] with S a common pad; pos: [B]."""
+        OWN position ``pos[b]`` (ragged lengths), its context read from the
+        pool pages by its row of the block table.  kp/vp: [Lstage,N,bs,H*D];
+        table: [B,T]; pos: [B].  Returns (x, k_slots, v_slots): the block
+        each sequence's new token went into, [Lstage,B,bs,H,D]."""
         cfg = self.cfg
         if first:
             x = jnp.take(sp["embed"], token[:, None], axis=0)
             if cfg.pos_emb == "learned":
                 x = x + jnp.take(sp["pos_table"], pos, axis=0)[:, None]
-        s_cache = kc.shape[2]
+        s_cache = table.shape[1] * kp.shape[2]
         slots = jnp.arange(s_cache, dtype=jnp.int32)[None, :]
         kv_positions = jnp.where(slots <= pos[:, None], slots, -1)   # [B,S]
-
-        def body(x, xs):
-            lp, k1, v1, w = xs
-            x, (k1, v1), _ = self._layer(x, lp, mode="decode_batch", kc=k1,
-                                         vc=v1, kv_positions=kv_positions,
-                                         pos=pos, window=w)
-            return x, (k1, v1)
-
-        x, (kc, vc) = jax.lax.scan(body, x, (sp["layers"], kc, vc,
-                                             sp["layer_window"]))
+        x, k_slots, v_slots = self._paged_layers(sp, x, kp, vp, table, pos,
+                                                 None, kv_positions)
         if last:
             x = norm_apply(cfg.norm, x, sp["final_norm"])
             x = self._unembed(sp, x)[:, 0]
-        return x, kc, vc
+        return x, k_slots, v_slots
 
-    def stage_prefill_chunk_batch(self, sp, x, kc, vc, pos, q_lens, *,
-                                  first: bool, last: bool, tokens=None):
+    def stage_prefill_chunk_batch_paged(self, sp, x, kp, vp, table, pos,
+                                        q_lens, *, first: bool, last: bool,
+                                        tokens=None):
         """Fused chunk-set pass: one prefill chunk of EACH of B in-flight
         sequences runs in a single pipeline pass.  Sequence b's chunk holds
         ``q_lens[b]`` valid tokens at absolute positions ``pos[b] ..
         pos[b]+q_lens[b]-1`` (rows past q_lens[b] are padding); each chunk
-        attends causally over its own cache prefix [0, pos[b]) plus itself.
-        Stage 0 passes `tokens` [B,Cmax]; the last stage returns each chunk's
-        final-valid-token logits [B,V] (only sequences whose prefill just
-        completed read theirs).  kc/vc: [Lstage,B,S,H,D]; pos/q_lens: [B]."""
+        attends causally over its own prefix [0, pos[b]), read from the pool
+        pages by block table, plus itself.  Stage 0 passes `tokens` [B,Cmax];
+        the last stage returns each chunk's final-valid-token logits [B,V]
+        (only sequences whose prefill just completed read theirs).
+        kp/vp: [Lstage,N,bs,H*D]; table: [B,T]; pos/q_lens: [B].  Returns
+        (x, k_slots, v_slots): the blocks each chunk went into,
+        [Lstage,B,nb*bs,H,D] (`block_window`)."""
         cfg = self.cfg
         if first:
             x = jnp.take(sp["embed"], tokens, axis=0)
@@ -349,19 +341,11 @@ class DecoderLM:
                                  jnp.clip(posm, 0, sp["pos_table"].shape[0] - 1),
                                  axis=0)
         c = x.shape[1]
-        s_cache = kc.shape[2]
+        s_cache = table.shape[1] * kp.shape[2]
         slots = jnp.arange(s_cache, dtype=jnp.int32)[None, :]
         kv_positions = jnp.where(slots < (pos + q_lens)[:, None], slots, -1)
-
-        def body(x, xs):
-            lp, k1, v1, w = xs
-            x, (k1, v1), _ = self._layer(x, lp, mode="decode_batch", kc=k1,
-                                         vc=v1, kv_positions=kv_positions,
-                                         pos=pos, q_lens=q_lens, window=w)
-            return x, (k1, v1)
-
-        x, (kc, vc) = jax.lax.scan(body, x, (sp["layers"], kc, vc,
-                                             sp["layer_window"]))
+        x, k_slots, v_slots = self._paged_layers(sp, x, kp, vp, table, pos,
+                                                 q_lens, kv_positions)
         if last:
             x = norm_apply(cfg.norm, x, sp["final_norm"])
             # per-sequence final valid token (ragged chunks): row q_lens[b]-1
@@ -369,7 +353,7 @@ class DecoderLM:
                    == (q_lens - 1)[:, None]).astype(x.dtype)       # [B,C]
             x = jnp.einsum("bc,bcd->bd", sel, x)
             x = self._unembed(sp, x[:, None])[:, 0]
-        return x, kc, vc
+        return x, k_slots, v_slots
 
     def stage_decode(self, sp, x, kc, vc, pos, *, first: bool, last: bool,
                      token=None):

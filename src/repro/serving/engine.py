@@ -143,9 +143,11 @@ class ServingEngine:
         telemetry.install(t)
         return t, True
 
-    @staticmethod
-    def _teardown_telemetry(t: telemetry.Telemetry, created: bool,
+    def _teardown_telemetry(self, t: telemetry.Telemetry, created: bool,
                             report: EngineReport) -> None:
+        # write-behinds still queued count on the streamer thread: let them
+        # land so two identical runs snapshot identical counters
+        self.cluster.streamer.quiesce()
         report.telemetry = t.snapshot()
         if created:
             telemetry.uninstall()
@@ -314,6 +316,10 @@ class ServingEngine:
         stops at eos) — unlike `run`, no request is held hostage by the
         longest peer in its microbatch.
 
+        A request whose prompt plus `max_new` tokens would outgrow the
+        block tables' fixed width raises ValueError before any request is
+        served.
+
         Prompts longer than `prefill_chunk_tokens` prefill CHUNK-INTERLEAVED:
         each round runs one chunk pass per in-flight prefill alongside one
         decode step per running sequence, so a long prompt stalls co-resident
@@ -333,6 +339,8 @@ class ServingEngine:
         """
         cl = self.cluster
         assert cl.paged, "run_continuous requires ServingEngine(..., paged=True)"
+        for r in requests:   # admission: refuse before any request is served
+            cl.check_context_fits(r.prompt_len + r.max_new - 1)
         sched = RoundScheduler(cl, requests, max_active=max_active)
         report = EngineReport(tokens={r.rid: r.tokens for r in requests})
         inj, prev = self._install_faults(fail_at, fault_plan, fault_injector,

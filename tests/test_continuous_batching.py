@@ -155,6 +155,31 @@ def test_prefix_sharing_saves_blocks():
     assert w.pool.peak_used_blocks < 9
 
 
+def test_paged_shared_prefix_and_chunk_padding_match_static():
+    """The paged data plane against the static cache end to end: prompts
+    that share two full blocks, prefilled in ragged 6-token chunks (so the
+    chunk-set passes carry padding rows), decode the same greedy tokens as
+    the non-paged path, which never touches a block table."""
+    shared = RNG.integers(0, CFG.vocab_size, (16,)).astype(np.int32)
+    prompts = [np.concatenate([shared, RNG.integers(0, CFG.vocab_size, (n,))
+                               ]).astype(np.int32) for n in (1, 1, 5, 5)]
+
+    def reqs():
+        return [Request(rid=i, prompt=p.copy(), max_new=5)
+                for i, p in enumerate(prompts)]
+
+    static = ServingEngine(CFG, MODEL, PARAMS, 2, mode="colocated",
+                           microbatch=2).run(reqs())
+    eng = ServingEngine(CFG, MODEL, PARAMS, 2, paged=True, kv_pool_blocks=64,
+                        prefill_chunk_tokens=6)
+    rep = eng.run_continuous(reqs(), max_active=4)
+    # 17- and 21-token prompts chunk as 6,6,5 and 6,6,6,3 in shared passes;
+    # unshared, the four sequences would peak at 3 + 3 + 4 + 4 blocks
+    assert eng.cluster.token_group[0].pool.peak_used_blocks < 14
+    for rid, toks in rep.tokens.items():
+        assert toks == static.tokens[rid][:5], rid
+
+
 def test_max_new_one_emits_exactly_one_token():
     # a request admitted and retired in the same round must not be decoded
     # past its budget by the round's step loop
@@ -240,7 +265,8 @@ def test_stages_run_on_their_devices_and_share_compiles():
     jits = MODEL.stage_jits(first=True, last=False)
     sizes = {k: f._cache_size() for k, f in jits.items()}
     again = ServingEngine(CFG, MODEL, PARAMS, 2, paged=True, devices=[dev])
-    assert again.cluster.token_group[0]._decode_batch is jits["decode_batch"]
+    assert again.cluster.token_group[0]._decode_batch_paged is \
+        jits["decode_batch_paged"]
     second = again.run_continuous(mkreqs(2), max_active=2)
     assert second.tokens == first.tokens
     assert {k: f._cache_size() for k, f in jits.items()} == sizes

@@ -262,8 +262,10 @@ def test_traced_run_covers_all_sources(traced):
             "emit.first_token", "cluster.kill", "recovery"} <= serve_names
     # the host-side layers of every stage op, tagged with their stage
     assert {"worker.gather", "worker.upload", "worker.stage",
-            "worker.readback", "kvpool.append", "kvpool.write_window",
+            "kvpool.append", "kvpool.write_window",
             "dejavulib.replicate", "engine.sample"} <= serve_names
+    # the paged passes read no window back: their pages stay on the device
+    assert "worker.readback" not in serve_names
     assert {e["args"]["wid"] for e in trace["tracks"]["serve"]["events"]
             if e["name"] == "worker.stage"} >= {0, 1}
     stream_names = {e["name"]
@@ -283,8 +285,10 @@ def _children(events, parent):
 
 def test_page_path_spans_nest_under_each_pass(served):
     """On a 2-stage model, every fused decode pass and chunk-set pass holds
-    the host page path's spans, once per stage, with byte counts equal to
-    the arrays moved; replication nests under the pass, sampling does not."""
+    the page path's spans, once per stage: the gather builds the block
+    tables, the upload moves only the tables and the per-sequence vectors,
+    and nothing is read back; replication nests under the pass with the
+    bytes the streamer sent, sampling does not."""
     from repro.serving import Request, ServingEngine
     cfg, model, params, _ = served
     # prompts of three chunks: after each admission's first chunk, the
@@ -304,14 +308,12 @@ def test_page_path_spans_nest_under_each_pass(served):
     snap = tracer.snapshot()
     evs = snap["tracks"]["serve"]["events"]
     xfers = snap["tracks"]["streamer"]["events"]
-    item = np.dtype(cfg.dtype).itemsize
-    row = cfg.num_kv_heads * cfg.resolved_head_dim * item   # one token, layer
-    layers = cfg.num_layers // 2
-    per_stage = ("worker.gather", "worker.stage", "worker.readback",
-                 "kvpool.write_window")
+    width = -(-cfg.max_seq_len // cfg.kv_block_size)    # table blocks
+    per_stage = ("worker.gather", "worker.stage", "kvpool.write_window")
     passes = [e for e in evs if e["name"] == "pass"]
     kinds = {e["args"]["kind"] for e in passes}
     assert {"fused_decode", "chunkset"} <= kinds
+    assert not [e for e in evs if e["name"] == "worker.readback"]
     for p in passes:
         kind = p["args"]["kind"]
         if kind not in ("fused_decode", "chunkset"):
@@ -323,17 +325,14 @@ def test_page_path_spans_nest_under_each_pass(served):
             assert sorted(e["args"]["wid"] for e in kids[name]) == [0, 1], \
                 (kind, name)
         n = len(p["args"]["rids"])
+        # int32 tables [n, width], positions, and chunk lengths
+        vectors = 1 if kind == "fused_decode" else 2
         for g in kids["worker.gather"]:
-            # K and V upload inside the gather, each as soon as it is dense
             ups = _children(evs, g)["worker.upload"]
-            assert [u["args"]["wid"] for u in ups] == [g["args"]["wid"]] * 2
-            assert g["args"]["n"] == n
-            assert [u["args"]["bytes"] for u in ups] == \
-                [layers * n * g["args"]["pad_to"] * row] * 2
+            assert [u["args"]["wid"] for u in ups] == [g["args"]["wid"]]
+            assert g["args"]["n"] == n and g["args"]["width"] == width
+            assert ups[0]["args"]["bytes"] == 4 * n * (width + vectors)
         if kind == "fused_decode":
-            tb = 8
-            for rb in kids["worker.readback"]:
-                assert rb["args"]["bytes"] == 2 * layers * n * tb * row
             rep, = kids["dejavulib.replicate"]
             assert rep["args"]["n"] == n
             # what the streamer sent while the pass waited for it
