@@ -64,13 +64,15 @@ def logit_errors(stats: Dict[str, np.ndarray], pos: np.ndarray,
         / stats["std"][pos]
 
 
-def compare(ref, params, arch: dict, items: Sequence[Item], pad_to: int,
-            device=None) -> Tuple[float, float, int]:
-    """(widest gap, widest logit error, tokens compared) over `items`."""
+def compare(ref, params, arch: dict, config: dict, items: Sequence[Item],
+            pad_to: int, device=None) -> Tuple[float, float, int]:
+    """(widest gap, widest logit error, tokens compared) over `items`;
+    `config` is the configuration file, whose own keys (a norm's epsilon,
+    say) the reference reads under its source's names."""
     if not items:
         return float("nan"), float("nan"), 0
     built = [inputs(it, pad_to) for it in items]
-    stats = ref.forward_stats(params, arch, [b[0] for b in built],
+    stats = ref.forward_stats(params, arch, config, [b[0] for b in built],
                               [b[1] for b in built], pad_to=pad_to,
                               device=device)
     g = np.concatenate([gaps(s, b[2]) for s, b in zip(stats, built)])
@@ -79,19 +81,19 @@ def compare(ref, params, arch: dict, items: Sequence[Item], pad_to: int,
     return float(np.max(g)), float(np.max(e)), int(g.size)
 
 
-def control(ref, params, arch: dict, items: Sequence[Item], pad_to: int,
-            device=None) -> Dict[str, float]:
+def control(ref, params, arch: dict, config: dict, items: Sequence[Item],
+            pad_to: int, device=None) -> Dict[str, float]:
     """The control: the reference computed in float8 in the program's place,
     read at the served positions of the same prompts and tokens.  Its gap
     is that of the token float8 puts first; its logit error that of float8's
     logit of the served token.  The program's own numbers on the same
     positions come alongside."""
     built = [inputs(it, pad_to) for it in items]
-    low = ref.forward_stats(params, arch, [b[0] for b in built],
+    low = ref.forward_stats(params, arch, config, [b[0] for b in built],
                             [b[1] for b in built], pad_to=pad_to,
                             control=True, device=device)
     both = [inputs(it, pad_to, [s["top"]]) for it, s in zip(items, low)]
-    stats = ref.forward_stats(params, arch, [b[0] for b in both],
+    stats = ref.forward_stats(params, arch, config, [b[0] for b in both],
                               [b[1] for b in both], pad_to=pad_to,
                               device=device)
     cat = np.concatenate

@@ -13,7 +13,10 @@ The system under test is ``ServingEngine(cfg, model, params, n_workers,
 paged=True, replication=True).run_continuous(requests, max_active=clients)``
 with the engine's defaults (fused rounds, chunked prefill).  The benchmark
 reaches it only through the request token lists, the sampler and the fault
-injector (see `window.py`); it injects no fault.
+injector (see `window.py`); it injects no fault.  A ``--trace 1`` run also
+installs a ``repro.core.tracing.Tracer`` while the profiler runs, and reads
+the ``repro.core.telemetry`` counters at the window's open and close; a
+``--trace 0`` run does neither.
 """
 from __future__ import annotations
 
@@ -24,12 +27,12 @@ import tempfile
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import jax
 import numpy as np
 
-from chipbench import correct, counters, peaks, spec, traffic, weights
+from chipbench import correct, counters, peaks, spans, spec, traffic, weights
 from chipbench.compiles import CompileCounter
 from chipbench.tracefile import TraceSummary, find_xplane, reduce_file
 from chipbench.window import (Recorder, TimedTokens, WindowClosed,
@@ -71,15 +74,15 @@ def devices_for(chips: int, require_chip: bool = True):
 class Run:
     """What a metric reader gets (metrics/<name>.py: read(run))."""
     cell: spec.Cell
-    sizes: counters.Sizes
+    ref: object                                # the reference module
+    arch: dict                                 # the served ArchConfig fields
     rec: Recorder
     window_s: float                            # t_close - t_open
     t_open: float
     t_close: float
     setup_s: float
     clients: int
-    stage_layers: List[int]
-    kv_pack_width: int
+    stages: List[Tuple[int, int]]              # each stage's layers lo, hi
     compiles_in_window: int = 0
     stream_bytes: int = 0                      # DejaVuLib bytes in window
     peak_bytes: Optional[int] = None
@@ -88,6 +91,10 @@ class Run:
     trace_t0: Optional[float] = None           # host clock at trace window
     trace_t1: Optional[float] = None
     gc_pauses: List[tuple] = field(default_factory=list)  # (t0, t1, gen)
+    # --trace 1 only: the program's spans while the profiler ran, and its
+    # telemetry counters' increase over the window
+    spans: Optional[List[spans.Span]] = None
+    counters: Optional[Dict[str, int]] = None
 
     def tokens_out(self) -> int:
         return tokens_in_window(self.rec.first, self.t_open, self.t_close)
@@ -113,8 +120,10 @@ class Run:
         return self.trace_t0 + (trace_s - self.trace.window[0])
 
 
-def _stage_layers(num_layers: int, n: int) -> List[int]:
-    return [len(s) for s in np.array_split(np.arange(num_layers), n)]
+def _stages(num_layers: int, n: int) -> List[Tuple[int, int]]:
+    """Each stage's layer range, split as the cluster splits them."""
+    return [(int(s[0]), int(s[-1]) + 1)
+            for s in np.array_split(np.arange(num_layers), n)]
 
 
 def _injector(rec: Recorder):
@@ -138,13 +147,15 @@ def _injector(rec: Recorder):
 class _Profiler:
     """Starts the profiler at a pass boundary TRACE_AT into the window and
     stops it at a pass boundary at least TRACE_MIN_S later, once a whole
-    decode pass ran inside; annotates the host's state meanwhile."""
+    decode pass ran inside; annotates the host's state meanwhile, and keeps
+    a tracer of the program's spans installed while it runs."""
 
     def __init__(self, seconds: float):
         self.seconds = seconds
         self.dir = tempfile.mkdtemp(prefix="chipbench-trace-")
         self.t0 = self.t1 = None
         self.window_ann = self.state_ann = None
+        self.tracer = self.prev_tracer = None
 
     def new_pass(self, rec: Recorder, kind: str) -> None:
         now = rec.clock()
@@ -161,9 +172,12 @@ class _Profiler:
     def _start(self, rec: Recorder) -> None:
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
+        from repro.core import tracing
         jax.profiler.start_trace(self.dir, profiler_options=opts)
         self.window_ann = jax.profiler.TraceAnnotation("bench.trace_window")
         self.window_ann.__enter__()
+        self.tracer = tracing.Tracer()
+        self.prev_tracer = tracing.install(self.tracer)
         self.t0 = rec.clock()
         rec.annotate = self._annotate
 
@@ -176,17 +190,24 @@ class _Profiler:
     def stop(self, rec: Recorder) -> None:
         if self.t0 is None or self.t1 is not None:
             return
+        from repro.core import tracing
         rec.annotate = None
         if self.state_ann is not None:
             self.state_ann.__exit__(None, None, None)
             self.state_ann = None
         self.t1 = rec.clock()
+        tracing.uninstall(self.prev_tracer)
         self.window_ann.__exit__(None, None, None)
         jax.profiler.stop_trace()
 
     def summary(self) -> Optional[TraceSummary]:
         path = find_xplane(self.dir)
         return reduce_file(path) if path else None
+
+    def program_spans(self) -> Optional[List[spans.Span]]:
+        if self.tracer is None:
+            return None
+        return spans.from_snapshot(self.tracer.snapshot())
 
     def cleanup(self) -> None:
         shutil.rmtree(self.dir, ignore_errors=True)
@@ -205,7 +226,8 @@ class Served:
         self.serving = dict(cfgfile["serving"])
         self.cfg = ArchConfig(name=cfgfile["name"], **self.arch)
         self.model = build_model(self.cfg)
-        self.sizes = counters.Sizes.from_arch(self.arch)
+        self.ref = spec.reference(cfgfile)
+        self.sizes = counters.Sizes.from_reference(self.ref, self.arch)
         self.clients = int(mix["clients"])
         self.stream = traffic.make_stream(mix, self.arch["vocab_size"], seed)
         bs = self.arch["kv_block_size"]
@@ -216,7 +238,7 @@ class Served:
         self.n_workers = int(self.serving["n_workers"])
 
     def make_weights(self) -> None:
-        dev = weights.make_params(self.arch, self.cell.config["init"],
+        dev = weights.make_params(self.model, self.cell.config["init"],
                                   self.seed, self.devices[0])
         self.params = jax.device_get(dev)
         del dev
@@ -313,6 +335,8 @@ def measure(c: Served, seconds: float, trace: bool, counter: CompileCounter,
     def snapshot(tag: str) -> None:
         box[tag] = (counter.compiles,
                     sum(box["eng"].transfer_summary().values()))
+        if trace:
+            box[tag + "_counters"] = _telemetry_counters()
 
     def close(now: float) -> bool:
         if rec.t_open is None:
@@ -346,12 +370,11 @@ def measure(c: Served, seconds: float, trace: bool, counter: CompileCounter,
     _free(eng)
     (c_open, b_open), (c_close, b_close) = box["open"], box["close"]
     t_close = box["t_close"]
-    run = Run(cell=c.cell, sizes=c.sizes, rec=rec,
+    run = Run(cell=c.cell, ref=c.ref, arch=c.arch, rec=rec,
               window_s=t_close - rec.t_open, t_open=rec.t_open,
               t_close=t_close,
               setup_s=rec.t_open - t_process, clients=c.clients,
-              stage_layers=_stage_layers(c.arch["num_layers"], c.n_workers),
-              kv_pack_width=int(c.serving["kv_pack_width"]),
+              stages=_stages(c.arch["num_layers"], c.n_workers),
               compiles_in_window=c_close - c_open,
               stream_bytes=b_close - b_open, peak_bytes=peak, peaks=pk,
               gc_pauses=[g for g in pauses
@@ -359,8 +382,20 @@ def measure(c: Served, seconds: float, trace: bool, counter: CompileCounter,
     if prof is not None:
         run.trace = prof.summary()
         run.trace_t0, run.trace_t1 = prof.t0, prof.t1
+        run.spans = prof.program_spans()
         prof.cleanup()
+        start = box["open_counters"]
+        run.counters = {k: v - start.get(k, 0)
+                        for k, v in box["close_counters"].items()}
     return run, reqs
+
+
+def _telemetry_counters() -> Dict[str, int]:
+    """The installed telemetry registry's counters (the engine installs one
+    for each run); empty without one."""
+    from repro.core import telemetry
+    t = telemetry.current()
+    return {} if t is None else dict(t.snapshot()["counters"])
 
 
 def compare(c: Served, reqs, rec: Recorder):
@@ -369,11 +404,8 @@ def compare(c: Served, reqs, rec: Recorder):
     items = [correct.Item(r.rid, r.prompt, list(r.tokens),
                           [rec.logit[r.rid][i] for i in range(len(r.tokens))])
              for r in reqs if len(r.tokens) > 0]
-    ref = spec.reference(c.cell.config)
-    arch = dict(c.arch, layer_norm_epsilon=c.cell.config[
-        "layer_norm_epsilon"])
-    return items, correct.compare(ref, c.params, arch, items,
-                                  pad_to=int(c.arch["max_seq_len"]),
+    return items, correct.compare(c.ref, c.params, c.arch, c.cell.config,
+                                  items, pad_to=int(c.arch["max_seq_len"]),
                                   device=c.devices[0])
 
 
@@ -416,6 +448,9 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         f"compilations in window {run.compiles_in_window}, "
         f"closed-loop order breaks {breaks}")
     log(window_profile(run))
+    if run.spans is not None:
+        log("traced " + spans.totals_line(run.spans, run.trace_t0,
+                                          run.trace_t1))
     device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
               "count": len(devs), "memory_peak_bytes": run.peak_bytes}
     out = {"correct": correct.passed(chk),

@@ -51,20 +51,31 @@ def _in(spans: Iterable[Span], t0: float, t1: float) -> List[Span]:
     return [s for s in spans if t0 <= s.t0 <= t1]
 
 
-def per_pass_ms(spans: Sequence[Span], passes, names: Sequence[str]
+def per_pass_ms(spans: Sequence[Span], passes, names: Sequence[str],
+                less: Sequence[Tuple[float, float]] = ()
                 ) -> Optional[float]:
     """Mean over `passes` (objects with ``t0``/``t1``, host seconds) of the
     self time of the spans named `names` that start inside each, summed
-    over stages (ms); None without passes or without any such span."""
+    over stages (ms); None without passes or without any such span.  The
+    time that each span's interval shares with the intervals `less` (host
+    seconds) is taken out of it: meant for spans with no children, whose
+    self time is their whole interval."""
     if not passes:
         return None
     total, seen = 0.0, False
     for p in passes:
         for s in _in(spans, p.t0, p.t1):
             if s.name in names:
-                total += s.self_s
+                total += s.self_s - overlap(s.t0, s.t1, less)
                 seen = True
     return 1e3 * total / len(passes) if seen else None
+
+
+def overlap(t0: float, t1: float,
+            intervals: Sequence[Tuple[float, float]]) -> float:
+    """Length of [t0, t1] that the union of `intervals` covers."""
+    return union_length([(max(a, t0), min(b, t1)) for a, b in intervals
+                         if b > t0 and a < t1])
 
 
 def arg_sum(spans: Sequence[Span], name: str, key: str, t0: float,
@@ -119,9 +130,5 @@ def unspanned_share(idle: Sequence[Tuple[float, float]],
     if idle_s <= 0:
         return None
     layers = [(a, b) for a, b, name in ann if name not in FRAMES]
-    covered = 0.0
-    for lo, hi in idle:
-        clipped = [(max(a, lo), min(b, hi)) for a, b in layers
-                   if b > lo and a < hi]
-        covered += union_length(clipped)
+    covered = sum(overlap(lo, hi, layers) for lo, hi in idle)
     return 100.0 * (1.0 - covered / idle_s)

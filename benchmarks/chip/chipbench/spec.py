@@ -2,7 +2,8 @@
 `BENCHMARK.json` gives them.
 
     configs/<config>.json      sizes as run, source, cuts, serving deployment
-    configs/<reference>.py     the plain float32 reference the file names
+    configs/<reference>.py     the plain float32 reference the file names,
+                               with its architecture's counts (`counters`)
     traffic/<mix>.json         parameters of the closed-loop traffic mix
     metrics/<metric>.py        ``read(run) -> float | None`` for one metric
 

@@ -1,18 +1,18 @@
-"""Seeded random weights, made on the device in one jitted call, in the
-type they are served in, laid out as the program's parameter tree
-(``DecoderLM``: layer-stacked ``layers``, ``embed`` [V, d] used as the tied
-head, learned ``pos_table``; norm gains stored as ``scale`` with gain
-= 1 + scale).  tests/test_chipbench_weights.py checks the layout against
-the program's own ``init``.
+"""Seeded random weights, made on the device in one jitted call, laid out
+as the tree the program's own ``init`` declares (``jax.eval_shape``), each
+leaf in the type that tree gives it: the served dtype, or float32 where the
+program keeps a leaf in float32 (an MoE router).  Nothing of the program's
+initial values is used, only the tree's paths, shapes and dtypes.
 
-Every leaf draws from its own key, ``fold_in(key(seed), leaf index)``, and
-every value is a normal with the configuration's ``init`` standard
-deviation for that leaf.  The plain reference reads these same arrays; it
+Every leaf draws from its own key, ``fold_in(key(seed), i)`` with ``i`` the
+leaf's index among the sorted '/'-joined paths, and every value is a float32
+normal with the configuration's ``init`` standard deviation for that leaf,
+cast to the leaf's dtype.  The plain reference reads these same arrays; it
 never calls the program.
 """
 from __future__ import annotations
 
-from typing import Dict
+from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -26,25 +26,13 @@ def root_key(seed: int):
                               (seed >> 32) & 0xFFFFFFFF)
 
 
-def shapes(arch: dict) -> Dict:
-    """{path: shape} of the served parameter tree."""
-    L, d = arch["num_layers"], arch["d_model"]
-    hd = arch.get("head_dim") or d // arch["num_heads"]
-    qd, kvd = arch["num_heads"] * hd, arch["num_kv_heads"] * hd
-    ff, V = arch["d_ff"], arch["vocab_size"]
-    out = {
-        "embed": (V, d),
-        "pos_table": (arch["max_seq_len"], d),
-        "layers/ln1/scale": (L, d), "layers/ln1/bias": (L, d),
-        "layers/attn/wq": (L, d, qd), "layers/attn/wk": (L, d, kvd),
-        "layers/attn/wv": (L, d, kvd), "layers/attn/wo": (L, qd, d),
-        "layers/ln2/scale": (L, d), "layers/ln2/bias": (L, d),
-        "layers/mlp/w_up": (L, d, ff), "layers/mlp/w_down": (L, ff, d),
-        "final_norm/scale": (d,), "final_norm/bias": (d,),
-    }
-    if not arch.get("tie_embeddings", False):
-        out["lm_head"] = (d, V)
-    return out
+def declared(model) -> Dict[str, Tuple[tuple, jnp.dtype]]:
+    """{path: (shape, dtype)} of the tree ``model.init`` declares, with the
+    path's dict keys joined by '/'."""
+    tree = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    leaves, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {"/".join(str(k.key) for k in path): (leaf.shape, leaf.dtype)
+            for path, leaf in leaves}
 
 
 def _std(init: dict, path: str) -> float:
@@ -66,16 +54,17 @@ def _nest(flat: Dict[str, object]) -> Dict:
     return tree
 
 
-def make_params(arch: dict, init: dict, seed: int, device=None):
-    """The whole served tree on `device`, from `seed`, in one jitted call."""
-    shp = shapes(arch)
-    dtype = jnp.dtype(arch.get("dtype", "bfloat16"))
-    paths = sorted(shp)
+def make_params(model, init: dict, seed: int, device=None):
+    """The whole served tree of `model` on `device`, from `seed`, in one
+    jitted call."""
+    leaves = declared(model)
+    paths = sorted(leaves)
+    stds = {p: _std(init, p) for p in paths}     # a missing std fails here
 
     def build(key):
-        return _nest({p: (_std(init, p) * jax.random.normal(
-            jax.random.fold_in(key, i), shp[p], jnp.float32)).astype(dtype)
-            for i, p in enumerate(paths)})
+        return _nest({p: (stds[p] * jax.random.normal(
+            jax.random.fold_in(key, i), leaves[p][0], jnp.float32))
+            .astype(leaves[p][1]) for i, p in enumerate(paths)})
 
     key = root_key(seed)
     if device is not None:

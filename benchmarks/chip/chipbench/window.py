@@ -12,7 +12,8 @@ The engine is driven through three public hooks only:
   engine fires for every request of a pass just before the pass runs.
 
 From those, `Recorder` keeps the first-emission time of every token and
-every pipeline pass (kind, host interval, batch, model FLOPs).
+every pipeline pass (kind, host interval, batch, model FLOPs, and for a
+decode pass the keys each sequence's query sees).
 The pure functions below compute the window's numbers from them; the tests
 drive them with synthetic timestamps.
 """
@@ -39,6 +40,7 @@ class Pass:
     t0: float                          # engine.step fire (host clock)
     t1: Optional[float] = None         # first sampler return, or next fire
     rids: List[int] = field(default_factory=list)
+    keys: List[int] = field(default_factory=list)   # decode: per rid
     flops: int = 0
 
     @property
@@ -141,16 +143,20 @@ class Recorder:
                                   self.clock())
             self.passes.append(cur)
         cur.rids.append(rid)
-        cur.flops += self._flops(kind, rid)
-
-    def _flops(self, kind: str, rid: int) -> int:
-        s, plen = self.sizes, self.plen[rid]
         if kind == "decode":
-            return counters.decode_flops(s, plen + self.ntok.get(rid, 0))
+            # the token fed back sits at plen + ntok - 1 and sees itself
+            keys = self.plen[rid] + self.ntok.get(rid, 0)
+            cur.keys.append(keys)
+            cur.flops += counters.decode_flops(self.sizes, keys)
+        else:
+            cur.flops += self._chunk_flops(rid)
+
+    def _chunk_flops(self, rid: int) -> int:
+        plen = self.plen[rid]
         pos0 = self.cursor.get(rid, 0)
         q = min(self.chunk, plen - pos0)
         self.cursor[rid] = pos0 + q
-        return counters.chunk_flops(s, pos0, q, pos0 + q == plen)
+        return counters.chunk_flops(self.sizes, pos0, q, pos0 + q == plen)
 
     # --- sampler -----------------------------------------------------
     def on_sample(self, start: bool, logit: Optional[float] = None) -> None:

@@ -16,6 +16,14 @@ through it, and they are freed before the next layer.
 activations, attention probabilities) rounded to float8 e4m3 with a
 per-tensor scale and accumulated in float32: the next precision below the
 bfloat16 the configurations serve in.
+
+`config` is the configuration file; the norm's epsilon is its own key
+``layer_norm_epsilon``.
+
+The counts of this layer that the benchmark's arithmetic needs
+(`chipbench.counters`) are here too, beside the layer they count: the
+FLOPs per token and per key behind ``device.step_mfu``, and the FLOPs and
+bytes of one stage decode program behind ``stage_decode_roofline``.
 """
 from __future__ import annotations
 
@@ -98,15 +106,16 @@ def _f32(tree, device):
                         .astype(jnp.float32), tree)
 
 
-def forward_stats(params: Dict, arch: dict, seqs: Sequence[np.ndarray],
-                  targets: Sequence[np.ndarray], *, pad_to: int,
-                  control: bool = False, device=None) -> List[Dict]:
+def forward_stats(params: Dict, arch: dict, config: dict,
+                  seqs: Sequence[np.ndarray], targets: Sequence[np.ndarray],
+                  *, pad_to: int, control: bool = False,
+                  device=None) -> List[Dict]:
     """`seqs[i]` (token ids) padded to `pad_to`; `targets[i]` [pad_to, K]
     int32 token ids whose logits to read at each position.  `params` is
     the host tree of served (bfloat16) weights.  Returns, per sequence, the
     `head_stats` arrays as numpy."""
     device = device or jax.devices()[0]
-    eps = float(arch.get("layer_norm_epsilon", 1e-5))
+    eps = float(config["layer_norm_epsilon"])
     heads = arch["num_heads"]
     table = jax.device_put(params["embed"], device).astype(jnp.float32)
     pos = jax.device_put(params["pos_table"], device).astype(jnp.float32)
@@ -128,3 +137,59 @@ def forward_stats(params: Dict, arch: dict, seqs: Sequence[np.ndarray],
                         control=control)
         out.append({k: np.asarray(v) for k, v in st.items()})
     return out
+
+
+# ---------------------------------------------------------------------------
+# counts (chipbench.counters): what one token, key or stage program needs
+# ---------------------------------------------------------------------------
+
+_ITEMSIZE = {"bfloat16": 2, "float16": 2, "float32": 4}
+
+
+def _widths(arch: dict):
+    d = arch["d_model"]
+    hd = arch.get("head_dim") or d // arch["num_heads"]
+    return d, arch["num_heads"] * hd, arch["num_kv_heads"] * hd
+
+
+def _layer_matmul_params(arch: dict) -> int:
+    """Weights of one layer's projections: q, k, v, o and the ungated MLP."""
+    d, qd, kvd = _widths(arch)
+    return d * qd + 2 * d * kvd + qd * d + 2 * d * arch["d_ff"]
+
+
+def matmul_flops_per_token(arch: dict) -> int:
+    return 2 * arch["num_layers"] * _layer_matmul_params(arch)
+
+
+def attention_flops_per_key(arch: dict) -> int:
+    """One query against one key, every layer: QK^T and PV."""
+    return 4 * arch["num_layers"] * _widths(arch)[1]
+
+
+def head_flops(arch: dict) -> int:
+    return 2 * arch["d_model"] * arch["vocab_size"]
+
+
+def stage_decode_cost(arch: dict, lo: int, hi: int, keys: Sequence[int],
+                      first: bool, last: bool) -> tuple:
+    """(FLOPs, bytes) that one execution of the stage decode program over
+    layers lo..hi-1 needs, for a batch of one new token per sequence whose
+    query sees ``keys[b]`` keys (itself included).  Bytes: the layers'
+    weights (projections and both norms' scale and bias) read once; on the
+    first stage the batch's token (and learned position) rows; on the last
+    the final norm and the LM head; each sequence's K and V at these layers
+    over its live context, ``keys[b] - 1`` positions read and the new one
+    written."""
+    d, qd, kvd = _widths(arch)
+    n, batch, isz = hi - lo, len(keys), _ITEMSIZE[arch["dtype"]]
+    per_layer = _layer_matmul_params(arch)
+    nbytes = n * (per_layer + 4 * d) + 2 * n * kvd * sum(keys)
+    flops = 2 * n * per_layer * batch + 4 * n * qd * sum(keys)
+    if first:
+        rows = 2 if arch.get("pos_emb") == "learned" else 1
+        nbytes += rows * batch * d
+    if last:
+        nbytes += arch["vocab_size"] * d + 2 * d
+        flops += head_flops(arch) * batch
+    return flops, nbytes * isz

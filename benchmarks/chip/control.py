@@ -52,11 +52,9 @@ def main(argv=None) -> int:
         line = {"seed": seed, "correct": out["correct"],
                 "checks": out["checks"], "metrics": out["metrics"]}
         if seed in controls:
-            arch = dict(c.arch, layer_norm_epsilon=c.cell.config[
-                "layer_norm_epsilon"])
-            ctl = correct.control(
-                spec.reference(c.cell.config), c.params, arch, items,
-                int(c.arch["max_seq_len"]), c.devices[0])
+            ctl = correct.control(c.ref, c.params, c.arch, c.cell.config,
+                                  items, int(c.arch["max_seq_len"]),
+                                  c.devices[0])
             ctl["checks"] = correct.control_checks(ctl, cell.config["limits"])
             ctl["correct"] = correct.passed(ctl["checks"])
             control_passed |= ctl["correct"]

@@ -85,9 +85,7 @@ def test_decode_leaving_kv_unchanged_is_not_correct(monkeypatch):
 
 def test_float8_control_fails_the_limits(clean):
     c, items = clean["_compared"]
-    ref = spec.reference(c.cell.config)
-    arch = dict(c.arch, layer_norm_epsilon=c.cell.config["layer_norm_epsilon"])
-    ctl = correct.control(ref, c.params, arch, items,
+    ctl = correct.control(c.ref, c.params, c.arch, c.cell.config, items,
                           int(c.arch["max_seq_len"]))
     assert ctl["program_logit_error"] == pytest.approx(
         clean["checks"]["logit_error"]["value"])

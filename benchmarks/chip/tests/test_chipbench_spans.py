@@ -85,6 +85,37 @@ def test_per_pass_means_sum_the_stages():
     assert spans.per_pass_ms(sp, [], ["worker.gather"]) is None
 
 
+def test_replicate_ms_leaves_out_the_device_time_of_other_programs():
+    """`dejavulib.replicate_ms` reads the replicate spans' self time less
+    the part in which the device ran a program other than the block read:
+    the wait at the span's first read is the stage worker's time."""
+    from chipbench import spec
+    from chipbench.harness import Run
+    from chipbench.tracefile import TraceSummary
+
+    sp = spans.from_snapshot(snapshot())
+    # the trace's clock is the host's minus 1 s; the first pass's span
+    # (1080-1095 ms) waits 5 ms for the stage program and 2 ms for the
+    # write-back, then reads its blocks (its own program, kept)
+    modules = {"jit_stage_decode_batch_paged": [(0.070, 0.015)],
+               "jit__write_window": [(0.085, 0.002)],
+               "jit__read_blocks": [(0.087, 0.002), (0.281, 0.002)]}
+    tr = TraceSummary(window=(0.0, 1.0), busy_s=0.1, devices=1,
+                      modules=modules)
+    run = Run(cell=None, ref=None, arch={}, rec=NS(passes=passes()),
+              window_s=1.0, t_open=1.0, t_close=2.0, setup_s=1.0,
+              clients=2, stages=[(0, 1), (1, 2)], trace=tr, trace_t0=1.0,
+              trace_t1=2.0, spans=sp)
+    read = spec.reader("dejavulib.replicate_ms")
+    assert read(run) == pytest.approx((15.0 - 7.0 + 15.0) / 2)
+    assert spans.overlap(1.080, 1.095, [(1.07, 1.085), (1.084, 1.087),
+                                        (1.2, 1.3)]) == pytest.approx(0.007)
+    run.trace = None
+    assert read(run) is None
+    run.trace, run.spans = tr, None
+    assert read(run) is None
+
+
 def test_byte_sums_and_window_totals():
     sp = spans.from_snapshot(snapshot())
     # the window holds both decode passes and the chunk-set pass
@@ -144,3 +175,54 @@ def test_span_annotations_leave_the_trace_reduction_unchanged():
     assert spanned == plain
     assert spanned.op_seconds == pytest.approx(
         {"jit_stage_decode_batch/fusion": 250e-6})
+
+
+def test_traced_run_keeps_the_program_spans_and_counters():
+    """A CPU serve through the harness: with --trace 1 the run holds the
+    spans of the traced part of the window and the telemetry counters'
+    increase over the window; with --trace 0 neither, and no tracer stays
+    installed after either."""
+    import time
+
+    from conftest import tiny_cell
+
+    from chipbench import harness, spec
+    from chipbench.tracefile import TraceSummary
+    from repro.core import tracing
+
+    def run(trace):
+        cell = tiny_cell()
+        cell.config["limits"] = {"widest_gap": 0.5, "logit_error": 0.1}
+        out = harness.run_cell(cell, 21, 1.5, trace,
+                               t_process=time.perf_counter(),
+                               require_chip=False, log=lambda m: None)
+        assert tracing.current() is None
+        return out["_run"]
+
+    traced = run(True)
+    assert traced.trace_t0 is not None and traced.spans
+    names = {s.name for s in traced.spans}
+    assert {"dejavulib.replicate", "worker.stage"} <= names
+    assert all(traced.trace_t0 <= s.t0 and s.t1 <= traced.trace_t1 + 1.0
+               for s in traced.spans)
+    dec = [p for p in traced.traced_passes() if p.kind == "decode"]
+    assert dec and all(len(p.keys) == p.batch for p in dec)
+    # the CPU leaves no device plane in the trace: with an empty one, no
+    # other program's time comes out of the replicate spans
+    replicate_ms = spec.reader("dejavulib.replicate_ms")
+    assert replicate_ms(traced) is None
+    traced.trace = TraceSummary(window=(0.0, 1.0), busy_s=0.0, devices=1)
+    ms = replicate_ms(traced)
+    assert ms > 0 and ms == spans.per_pass_ms(traced.spans, dec,
+                                              ["dejavulib.replicate"])
+    # blocks read off the pages to replicate them, in the window only
+    assert traced.counters["kvpool.block_reads"] > 0
+    assert all(v >= 0 for v in traced.counters.values())
+    per_token = spec.reader("kvpool.block_read_bytes_per_token")(traced)
+    assert per_token == traced.counters["kvpool.block_read_bytes"] \
+        / traced.tokens_out() > 0
+
+    plain = run(False)
+    assert plain.spans is None and plain.counters is None
+    assert replicate_ms(plain) is None
+    assert spec.reader("kvpool.block_read_bytes_per_token")(plain) is None

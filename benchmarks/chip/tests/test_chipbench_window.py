@@ -9,9 +9,10 @@ import pytest
 from chipbench import counters, window
 from chipbench.window import Recorder, TimedTokens
 
-SIZES = counters.Sizes(layers=2, d_model=64, heads=4, kv_heads=4,
-                       head_dim=16, d_ff=128, vocab=256, gated_mlp=False,
-                       itemsize=2)
+# a 2-layer pre-LN decoder, 64 wide, 4 heads of 16, ungated MLP 128, vocab
+# 256: 2 x 2 x (4 x 64 x 64 + 2 x 64 x 128); 4 x 2 x 64; 2 x 64 x 256
+SIZES = counters.Sizes(matmul_flops_per_token=131_072,
+                       attention_flops_per_key=512, head_flops=32_768)
 
 
 class Clock:
@@ -117,6 +118,7 @@ def test_recorder_groups_passes_and_counts_flops():
     assert p[1].flops == counters.chunk_flops(SIZES, 0, 10, True)
     assert p[2].flops == counters.chunk_flops(SIZES, 16, 4, True)
     assert p[3].flops == counters.decode_flops(SIZES, 10 + 1)
+    assert p[3].keys == [10 + 1] and p[0].keys == []
 
 
 def test_fill_decides_when_the_window_may_open():
@@ -153,9 +155,9 @@ def test_window_profile_lists_passes_and_collector_pauses():
         rec.on_step("decode-r1")
         clk.t = t1
         rec.on_sample(False)
-    run = Run(cell=None, sizes=SIZES, rec=rec, window_s=8.0, t_open=1.0,
-              t_close=9.0, setup_s=1.0, clients=2, stage_layers=[1, 1],
-              kv_pack_width=8,
+    run = Run(cell=None, ref=None, arch={}, rec=rec, window_s=8.0,
+              t_open=1.0, t_close=9.0, setup_s=1.0, clients=2,
+              stages=[(0, 1), (1, 2)],
               gc_pauses=[(3.0, 3.25, 2), (5.0, 5.001, 0), (6.0, 6.002, 0)])
     line = window_profile(run)
     assert "d2:1500 d2:1500 d2:5000;" in line
